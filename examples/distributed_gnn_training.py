@@ -27,11 +27,14 @@ from repro.data.pipeline import gnn_features
 from repro.distributed.halo import build_halo_program, make_partitioned_spmm
 from repro.distributed.placement import build_layout, collective_bytes_estimate
 from repro.graphs import datasets
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_replay_mesh
 from repro.models import gnn
 from repro.optim import adamw
 
 
 def main() -> None:
+    enable_compile_cache()
     n_shards = 4
     graph = datasets.load("gis", scale=0.003)
     print(graph.summary())
@@ -47,7 +50,7 @@ def main() -> None:
     # --- Build the partition-aware layout + halo program (DiDiC placement).
     layout = build_layout(graph, didic_parts, n_shards)
     prog = build_halo_program(graph, layout)
-    mesh = jax.make_mesh((n_shards,), ("data",))
+    mesh = make_replay_mesh(n_shards)
     spmm = make_partitioned_spmm(prog, mesh, ("data",))
     print(f"  halo program: block={prog.block} B_max={prog.b_max} G_max={prog.g_max} "
           f"collective={prog.halo_bytes(d_hidden)/1e6:.2f} MB/step")

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.pipeline import LmDataConfig, lm_token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.moe import MoeConfig
 from repro.models.transformer import TransformerConfig, init_params, loss_fn
 from repro.optim.adamw import AdamWConfig
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--moe", action="store_true", help="deepseek-moe-style reduced config")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # Reduced deepseek-moe-16b family config (CPU-sized).
     moe = MoeConfig(n_experts=8, top_k=2, n_shared=1, d_ff=128) if args.moe else None

@@ -16,6 +16,7 @@ from repro.core.didic import didic_partition, didic_refine
 from repro.core.dynamism import apply_dynamism, generate_dynamism
 from repro.core.framework import PartitionedGraphService
 from repro.graphs import datasets
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=0.01)
     ap.add_argument("--k", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = PaperExperimentConfig(scale=args.scale)
 
     for name in cfg.datasets:

@@ -10,9 +10,11 @@ from repro.core import metrics, partitioners
 from repro.core.didic import DidicConfig, didic_partition
 from repro.core.framework import PartitionedGraphService
 from repro.graphs import datasets
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1. Load a graph dataset (synthetic Twitter crawl, ~6k users).
     graph = datasets.load("twitter", scale=0.01)
     print(graph.summary())

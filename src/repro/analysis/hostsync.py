@@ -29,6 +29,7 @@ from repro.analysis.framework import (
 
 _JIT_NAMES = {"jax.jit", "jit"}
 _SHARD_MAP_NAMES = {
+    "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
     "shard_map",
 }

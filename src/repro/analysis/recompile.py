@@ -51,8 +51,9 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 from repro.analysis.framework import Finding
 
+# Depending on its version, JAX prints the argument types as a list or a tuple.
 _COMPILE_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types (\[.*\])\."
+    r"Compiling ([^\s]+) with global shapes and types (\[.*\]|\(.*\))\."
     r"\s*Argument mapping"
 )
 #: jax loggers that announce compilations when ``jax_log_compiles`` is on.

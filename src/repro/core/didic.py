@@ -66,6 +66,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.graphs.structure import Graph
 
@@ -124,20 +125,21 @@ def _edge_coefficients(graph: Graph) -> Tuple[np.ndarray, np.ndarray, np.ndarray
     return out
 
 
-def _spmm_segment(ce: jax.Array, s: jax.Array, r: jax.Array, n: int, x: jax.Array) -> jax.Array:
+def _spmm_segment(ce: jax.Array, s: jax.Array, r: jax.Array, x: jax.Array) -> jax.Array:
     """A_c @ X via gather + segment_sum over the symmetrized COO edges."""
     contrib = ce[:, None] * jnp.take(x, r, axis=0)
-    return jax.ops.segment_sum(contrib, s, num_segments=n)
+    return jax.ops.segment_sum(contrib, s, num_segments=x.shape[0])
 
 
-def make_spmm(graph: Graph, config: DidicConfig) -> Tuple[Callable[[jax.Array], jax.Array], jax.Array]:
+def make_spmm(graph: Graph, config: DidicConfig) -> Tuple[Partial, jax.Array]:
     """Return (spmm(X) -> A_c @ X, degc) for the DiDiC coefficient matrix.
 
-    Cached *on the graph object* (lifetime-tied — an id()-keyed global
-    cache would alias recycled addresses) so repeated partition/refine
-    calls reuse one jitted step: maintenance iterations must not pay a
-    fresh trace+compile (the paper's ~1 % maintenance-cost claim is about
-    computation, not compilation).
+    ``spmm`` is a :class:`jax.tree_util.Partial` whose arguments are the
+    graph's tables, so the jitted step takes them as inputs; as constants
+    they would make each compiled step, and its persistent-cache entry,
+    grow with the graph. Cached *on the graph object* (lifetime-tied — an
+    id()-keyed global cache would alias recycled addresses) so repeated
+    partition/refine calls reuse the device tables.
     """
     cache = graph.__dict__.setdefault("_didic_spmm_cache", {})
     cache_key = (config.use_kernel, config.block_size)
@@ -159,39 +161,36 @@ def make_spmm(graph: Graph, config: DidicConfig) -> Tuple[Callable[[jax.Array], 
             xp = jnp.pad(x, ((0, pad), (0, 0)))
             return kernel_mm(xp)[: x.shape[0]]
 
-        _SPMM_CACHE[cache_key] = (spmm_fn, jnp.asarray(degc))
+        _SPMM_CACHE[cache_key] = (Partial(spmm_fn), jnp.asarray(degc))
         return _SPMM_CACHE[cache_key]
-    s_j, r_j, ce_j = jnp.asarray(s), jnp.asarray(r), jnp.asarray(ce)
-    n = graph.n_nodes
-
-    def spmm_segment_fn(x: jax.Array) -> jax.Array:  # plain def: carries the
-        return _spmm_segment(ce_j, s_j, r_j, n, x)   # step cache attribute
-
-    _SPMM_CACHE[cache_key] = (spmm_segment_fn, jnp.asarray(degc))
+    tables = (jnp.asarray(ce), jnp.asarray(s), jnp.asarray(r))
+    _SPMM_CACHE[cache_key] = (Partial(_spmm_segment, *tables), jnp.asarray(degc))
     return _SPMM_CACHE[cache_key]
 
 
-def _make_step(spmm: Callable, degc: jax.Array, config: DidicConfig):
-    """Build the jitted single-iteration function (closes over the graph).
+_STEP_CACHE: dict = {}
 
-    Cached on the spmm callable (which the graph owns), so the step's
-    lifetime is tied to the graph's — no id() aliasing.
+
+def _make_step(spmm: Partial, degc: jax.Array, config: DidicConfig):
+    """The single-iteration function for ``spmm``'s graph.
+
+    The jitted step is shared per config: ``spmm`` (a
+    :class:`jax.tree_util.Partial` over the graph's tables) and ``degc``
+    are its arguments, so no graph array becomes a compiled constant.
     """
-    cache = getattr(spmm, "_didic_step_cache", None)
-    if cache is None:
-        cache = {}
-        try:
-            spmm._didic_step_cache = cache
-        except AttributeError:  # functools.partial accepts attributes; be safe
-            pass
-    if config in cache:
-        return cache[config]
+    jitted = _STEP_CACHE.get(config)
+    if jitted is None:
+        jitted = _STEP_CACHE[config] = _jit_step(config)
+    return functools.partial(jitted, spmm=spmm, degc=degc)
+
+
+def _jit_step(config: DidicConfig):
     k = config.k
-    safe_deg = jnp.maximum(degc, 1e-6)
 
     @jax.jit
-    def step(w, l, parts, beta, key, smooth_steps):
+    def step(w, l, parts, beta, key, smooth_steps, *, spmm, degc):
         n = w.shape[0]
+        safe_deg = jnp.maximum(degc, 1e-6)
         onehot = (parts[:, None] == jnp.arange(k, dtype=parts.dtype)[None, :]).astype(w.dtype)
         # Fresh per-member secondary seed (Eq. 4.5 each iteration; fix #1),
         # with an ε-floor: a system that loses all members would otherwise
@@ -236,7 +235,6 @@ def _make_step(spmm: Callable, degc: jax.Array, config: DidicConfig):
         parts = jnp.where(commit, new_parts, parts)
         return w, l, parts, beta
 
-    cache[config] = step
     return step
 
 

@@ -60,6 +60,7 @@ from repro.core.didic import (
 )
 from repro.core import partitioners
 from repro.graphs.structure import Graph
+from repro.launch.mesh import auto_axes
 
 if False:  # typing only — real imports are lazy (core ↔ distributed cycle)
     from repro.distributed.placement import PartitionedLayout  # noqa: F401
@@ -120,8 +121,12 @@ def _mesh_program_build(graph, mesh, data_axes, n_shards, bootstrap_parts,
     s, _, _ = graph.undirected
     degc_host = np.zeros(graph.n_nodes, dtype=np.float64)
     np.add.at(degc_host, s, ce)
-    degc = jnp.asarray(layout.scatter_features(degc_host.astype(np.float32)))
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    degc = jax.device_put(
+        layout.scatter_features(degc_host.astype(np.float32)),
+        NamedSharding(auto_axes(mesh), P(data_axes)),
+    )
     return (layout, spmm_halo, degc)
 
 
@@ -283,7 +288,6 @@ def _make_mesh_overlay_step(mesh, data_axes: Tuple[str, ...],
     if step is not None:
         return step
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     k = config.k
@@ -300,12 +304,12 @@ def _make_mesh_overlay_step(mesh, data_axes: Tuple[str, ...],
         contrib = (ew[0] * emask[0])[:, None] * xx[esrc[0]]
         return jax.ops.segment_sum(contrib, edst[0], num_segments=block)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec_x,) + (spec_tab,) * 6,
         out_specs=spec_x,
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -435,6 +439,7 @@ def didic_partition_distributed(
     Returns (parts[N] in ORIGINAL vertex ids, the bootstrap layout used).
     ``config.k`` must be a multiple of the data-shard count.
     """
+    mesh = auto_axes(mesh)
     layout, spmm_halo, degc = _mesh_program(graph, mesh, data_axes, bootstrap_parts)
     if config.k % layout.n_shards:
         raise ValueError(
@@ -486,6 +491,7 @@ def didic_refine_distributed(
     """
     from repro.core.didic import _capture_pins, _restore_pins
 
+    mesh = auto_axes(mesh)
     config = dataclasses.replace(config, commit_prob=1.0)
     pinned, before = _capture_pins(parts, pinned)
     if graph.store is not None:

@@ -29,11 +29,12 @@ shortest-path sweep in vertex-major layout ``g [W, chunk]``. One round
 relaxes every in-edge of every window vertex for every op at once — a
 min-plus gather over a capped padded in-neighbor layout with a
 scatter-min spill for over-cap rows. The gather runs through
-:func:`repro.kernels.frontier.frontier_relax`: the Pallas
-scalar-prefetch kernel on TPU, the unrolled-slot XLA form on CPU —
-bit-identical either way (min and float32 add are exact and slot-order
-independent), selectable via ``use_kernel`` /
-``REPRO_FRONTIER_KERNEL=1``. With the default
+:func:`repro.kernels.frontier.frontier_relax`: the unrolled-slot XLA form
+by default on every backend, the Pallas row-DMA kernel with
+``use_kernel=True`` — bit-identical either way (min and float32 add are
+exact and slot-order independent). On a TPU v5e the kernel is the slower
+of the two at the whole-graph GIS shape (117.55 vs 85.88 ms per relax),
+so it stays opt-in until a workload shows it winning. With the default
 ``delta_scale=None`` each round is a full frontier Bellman–Ford sweep
 (minimum rounds on a dense backend); a finite ``delta_scale`` instead
 gates relaxation to the op's current distance bucket of width
@@ -84,7 +85,6 @@ to replay the same log sharded over mesh data axes, bit-exactly.
 
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Tuple
 
 import jax
@@ -92,7 +92,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graphs.structure import Graph, padded_neighbors
-from repro.kernels import on_tpu, resolve_interpret
+from repro.kernels import resolve_interpret
 from repro.kernels.frontier import frontier_relax
 
 __all__ = ["BatchedTrafficEngine", "execute_ops_batched", "get_engine"]
@@ -265,12 +265,9 @@ class BatchedTrafficEngine:
 
         self.pattern = pattern
         self.max_expansions = resolve_max_expansions(max_expansions)
-        # Relaxation path: Pallas frontier kernel on TPU, unrolled XLA
-        # gather on CPU; REPRO_FRONTIER_KERNEL=1/0 or the ctor arg
-        # overrides. Both resolved once, here — never at trace time.
-        if use_kernel is None:
-            env = os.environ.get("REPRO_FRONTIER_KERNEL")
-            use_kernel = env == "1" if env in ("0", "1") else on_tpu()
+        # Relaxation path: unrolled XLA gather unless the caller asks for
+        # the Pallas frontier kernel. Both resolved once, here — never at
+        # trace time.
         self.use_kernel = bool(use_kernel)
         self.interpret = resolve_interpret()
 
@@ -925,8 +922,8 @@ def get_engine(
     """Engine cache: store-lifetime for overlay graphs, graph-lifetime
     otherwise (same idiom as didic.make_spmm).
 
-    ``max_expansions`` is normalized before keying, so ``None`` and an
-    explicit default resolve to the *same* engine — the engine's value is
+    ``max_expansions`` and ``use_kernel`` are normalized before keying,
+    so ``None`` and an explicit default resolve to the *same* engine — the engine's value is
     authoritative for every path (batched, sharded, redo, resident).
     For a store-backed graph the engine is keyed on the
     :class:`~repro.graphs.structure.GraphStore` by engine parameters
@@ -934,7 +931,7 @@ def get_engine(
     place, so compiled closures survive growth.
     """
     key = (pattern, chunk, resolve_max_expansions(max_expansions),
-           delta_scale, use_kernel)
+           delta_scale, bool(use_kernel))
     store = graph.store
     if store is not None:
         skey = ("engine",) + key

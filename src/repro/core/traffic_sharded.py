@@ -86,6 +86,7 @@ from repro.distributed.counters import (
     make_scatter_psum,
 )
 from repro.graphs.structure import Graph
+from repro.launch.mesh import auto_axes
 
 __all__ = [
     "ResidentReplayState",
@@ -245,7 +246,7 @@ class ShardedTrafficReplayer:
         use_kernel: Optional[bool] = None,
     ):
         self.graph = graph
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         self.data_axes = tuple(data_axes)
         self.n_shards = data_shard_count(mesh, self.data_axes)
         self.engine = get_engine(
@@ -285,8 +286,6 @@ class ShardedTrafficReplayer:
 
     # =================================================== linear BFS patterns
     def _build_bfs_fns(self) -> None:
-        from jax.experimental.shard_map import shard_map
-
         eng = self.engine
         t, n = eng.max_levels, eng._n_rows
         axes = self.data_axes
@@ -319,12 +318,12 @@ class ShardedTrafficReplayer:
                 tm = c[lvl_i] + push
             return jax.lax.psum(tm, axes)
 
-        self._tm_fn = jax.jit(shard_map(
+        self._tm_fn = jax.jit(jax.shard_map(
             tm_body,
             mesh=self.mesh,
             in_specs=(s2, s2, s2, P(), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         ))
 
     def _shard_pad(self, arr: np.ndarray, fill, width: Optional[int] = None) -> np.ndarray:
@@ -401,8 +400,6 @@ class ShardedTrafficReplayer:
 
     # ====================================================== GIS batched SSSP
     def _build_sssp_fns(self) -> None:
-        from jax.experimental.shard_map import shard_map
-
         eng = self.engine
         axes = self.data_axes
         s2 = P(axes, None)
@@ -423,12 +420,12 @@ class ShardedTrafficReplayer:
             return (member[None], foot[None], edges[None], cross[None],
                     f_dst[None], done[None])
 
-        self._solve_fn = jax.jit(shard_map(
+        self._solve_fn = jax.jit(jax.shard_map(
             solve_body,
             mesh=self.mesh,
             in_specs=(s2, s2, s2, s2, s2, s2, s2, s3, s3, s2, s2, s2, s3, P()),
             out_specs=(s3, s3, s2, s2, s2, s2),
-            check_rep=False,
+            check_vma=False,
         ))
 
         # Redo (whole-graph) pass: the gather layout is op- and
@@ -450,12 +447,12 @@ class ShardedTrafficReplayer:
             return (member[None], foot[None], edges[None], cross[None],
                     f_dst[None], done[None])
 
-        self._solve_full_fn = jax.jit(shard_map(
+        self._solve_full_fn = jax.jit(jax.shard_map(
             solve_full_body,
             mesh=self.mesh,
             in_specs=(s2, s2, s2, s2, s3) + (P(),) * 9,
             out_specs=(s3, s3, s2, s2, s2, s2),
-            check_rep=False,
+            check_vma=False,
         ))
         self._full_static_dev = None
         self._scatter_psum_shared = None
@@ -931,8 +928,10 @@ def get_replayer(
     adopts each grown graph in place, so a growth step is a cache *hit*
     and reuses every compiled closure.
     """
+    mesh = auto_axes(mesh)
     key = (pattern, mesh, tuple(data_axes), chunk,
-           resolve_max_expansions(max_expansions), delta_scale, use_kernel)
+           resolve_max_expansions(max_expansions), delta_scale,
+           bool(use_kernel))
     store = graph.store
     if store is not None:
         skey = ("replayer",) + key

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.launch.mesh import auto_axes
+
 __all__ = ["CounterAccumulator", "data_shard_count", "make_scatter_psum"]
 
 
@@ -57,19 +59,19 @@ def make_scatter_psum(
     scatters through — the shape of the sharded replayer's whole-graph
     redo pass, where all shards solve on the same replicated layout.
     """
-    from jax.experimental.shard_map import shard_map
+    mesh = auto_axes(mesh)
 
     def body(ids, mass):
         row = ids if shared_ids else ids[0]
         local = jnp.zeros((n_rows,), jnp.int32).at[row].add(mass[0], mode="drop")
         return jax.lax.psum(local, data_axes)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P() if shared_ids else P(data_axes, None), P(data_axes, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

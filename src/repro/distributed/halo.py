@@ -19,15 +19,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.tree_util import Partial
 
 from repro.distributed.placement import PartitionedLayout
 from repro.graphs.structure import Graph
+from repro.launch.mesh import auto_axes
 
 __all__ = ["HaloProgram", "build_halo_program", "make_partitioned_spmm"]
 
@@ -149,27 +151,34 @@ def build_halo_program(
 
 def make_partitioned_spmm(
     program: HaloProgram, mesh: Mesh, data_axes: Tuple[str, ...] = ("data",)
-) -> Callable[[jax.Array], jax.Array]:
+) -> Partial:
     """Return ``x [S·block, F] → Σ_e w·x[src]`` with halo exchange.
 
     ``x`` must be sharded ``P(data_axes, None)``; the result has the same
     sharding. This is the distributed form of the DiDiC/GCN SpMM: local
-    segment-sum + one all-gather of boundary rows.
+    segment-sum + one all-gather of boundary rows. The halo tables live
+    sharded on the mesh and are the :class:`jax.tree_util.Partial`'s
+    arguments, so a jitted caller takes them as inputs, not constants.
     """
+    mesh = auto_axes(mesh)
     block = program.block
     spec_x = P(data_axes, None)
     spec_tab = P(data_axes, None)
 
-    tabs = (
-        jnp.asarray(program.edge_src),
-        jnp.asarray(program.edge_dst),
-        jnp.asarray(program.edge_w),
-        jnp.asarray(program.edge_mask),
-        jnp.asarray(program.boundary_idx),
-        jnp.asarray(program.ghost_src),
+    tab_sharding = NamedSharding(mesh, spec_tab)
+    tabs = tuple(
+        jax.device_put(np.asarray(t), tab_sharding)
+        for t in (
+            program.edge_src,
+            program.edge_dst,
+            program.edge_w,
+            program.edge_mask,
+            program.boundary_idx,
+            program.ghost_src,
+        )
     )
 
-    def body(x_l, esrc, edst, ew, emask, bidx, gsrc):
+    def body(esrc, edst, ew, emask, bidx, gsrc, x_l):
         # shapes per shard: x_l [block, F]; tables [1, ...]
         x_l = x_l.reshape(block, -1)
         boundary = x_l[bidx[0]]                                   # [B_max, F]
@@ -181,18 +190,11 @@ def make_partitioned_spmm(
         agg = jax.ops.segment_sum(contrib, edst[0], num_segments=block)
         return agg
 
-    from jax.experimental.shard_map import shard_map
-
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(spec_x,) + (spec_tab,) * 6,
+        in_specs=(spec_tab,) * 6 + (spec_x,),
         out_specs=spec_x,
-        check_rep=False,
+        check_vma=False,
     )
-
-    @jax.jit
-    def spmm(x: jax.Array) -> jax.Array:
-        return smapped(x, *tabs)
-
-    return spmm
+    return Partial(jax.jit(smapped), *tabs)

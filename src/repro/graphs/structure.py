@@ -180,12 +180,22 @@ def coalesce_edges(
 def symmetrize(
     senders: np.ndarray, receivers: np.ndarray, weights: np.ndarray, n_nodes: int
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Return the undirected (symmetrized, coalesced, loop-free) edge set."""
-    s = np.concatenate([senders, receivers])
-    r = np.concatenate([receivers, senders])
-    w = np.concatenate([weights, weights])
-    keep = s != r
-    return coalesce_edges(s[keep], r[keep], w[keep], n_nodes)
+    """Return the undirected (symmetrized, coalesced, loop-free) edge set.
+
+    Each vertex pair's weight is summed once, over its ``(min, max)``
+    orientation, and then mirrored, so ``w(u, v)`` and ``w(v, u)`` are the
+    same float32 value whatever order the duplicates came in.
+    """
+    senders, receivers = np.asarray(senders), np.asarray(receivers)
+    keep = senders != receivers
+    lo, hi, w = coalesce_edges(
+        np.minimum(senders, receivers)[keep], np.maximum(senders, receivers)[keep],
+        np.asarray(weights)[keep], n_nodes,
+    )
+    return coalesce_edges(
+        np.concatenate([lo, hi]), np.concatenate([hi, lo]),
+        np.concatenate([w, w]), n_nodes,
+    )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,21 +1,26 @@
-"""Pallas TPU kernel: frontier gather — scalar-prefetched neighbor reduce.
+"""Pallas TPU kernel: frontier gather — DMA row gather + neighbor reduce.
 
 The batched traffic engine's hot loop (DESIGN: ISSUE 1) is "advance every
 operation's frontier one level": for each vertex ``v``, reduce the frontier
 rows of its in-neighbors. With the padded in-neighbor layout
-(:class:`repro.graphs.structure.PaddedNeighbors`) this is the same
-scalar-prefetched row-gather shape as the EmbeddingBag kernel: neighbor ids
-live in SMEM ahead of the grid, and each grid step streams exactly one
-frontier row tile into VMEM — one row fetch per (vertex, neighbor-slot),
-the roofline minimum for a frontier sweep. No scatter anywhere, so the
-reduction is branch-free on the VPU.
+(:class:`repro.graphs.structure.PaddedNeighbors`) that is a row gather
+followed by an elementwise reduce over the neighbor slots.
 
-Grid: ``(V, C_tiles, D)`` — the neighbor-slot reduction axis last, so the
-output tile stays VMEM-resident across its accumulation steps.
-``mode="sum"`` accumulates ``w · row``
-(multiplicity propagation / BFS expansion); ``mode="min"`` accumulates
-``min(acc, row + w)`` (one min-plus relaxation of the bucketed SSSP), with
-padded slots carrying ``w = +inf``.
+Grid: ``(V / ROWS, C / c_tile)``. Each step owns a ``[ROWS, c_tile]``
+output tile. The step's slice of the transposed neighbor table
+``nbr.T [D, ROWS]`` is blocked into SMEM, the frontier ``x`` stays in HBM,
+and the step starts one row DMA per (vertex, neighbor slot) into a
+``[D, ROWS, c_tile]`` VMEM buffer — one row fetch per edge slot, the
+roofline minimum for a frontier sweep — then reduces the slots on the
+VPU. The weights arrive as an ordinary ``[ROWS, D]`` VMEM block. Nothing
+grows with the graph except the grid: SMEM holds ``2 · D · ROWS`` ids
+whatever ``V`` is, so paper-scale layouts (``V`` ≈ 786 k) fit the chip's
+1 MiB of SMEM, which whole-table scalar prefetch did not.
+
+``mode="sum"`` accumulates ``w · row`` (multiplicity propagation / BFS
+expansion); ``mode="min"`` accumulates ``min(acc, row + w)`` (one min-plus
+relaxation of the bucketed SSSP), with padded slots carrying ``w = +inf``.
+Both reduce the slots in ascending order, like the XLA reference.
 """
 
 from __future__ import annotations
@@ -30,29 +35,51 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
-
-def _frontier_sum_kernel(nbr_ref, w_ref, x_ref, o_ref):
-    v = pl.program_id(0)
-    d = pl.program_id(2)
-
-    @pl.when(d == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    w = w_ref[v, d].astype(o_ref.dtype)
-    o_ref[...] += w * x_ref[...].astype(o_ref.dtype)
+# Output rows per grid step (a multiple of 128, so the SMEM id block is
+# lane-aligned). The VMEM gather buffer is D · ROWS · c_tile · 4 bytes.
+ROWS = 128
+# Widest neighbor layout the kernel takes: the buffer stays ≤ 4 MiB of
+# VMEM at c_tile=128. The engine caps its layouts far below this.
+MAX_SLOTS = 64
 
 
-def _frontier_min_kernel(nbr_ref, w_ref, x_ref, o_ref):
-    v = pl.program_id(0)
-    d = pl.program_id(2)
+def _make_kernel(mode: str, d: int, rows: int):
+    def kernel(nbr_ref, w_ref, x_hbm, o_ref, buf, sem):
+        c_tile = o_ref.shape[1]
+        col = pl.program_id(1) * c_tile
 
-    @pl.when(d == 0)
-    def _init():
-        o_ref[...] = jnp.full_like(o_ref, jnp.inf)
+        def row_copy(i, j, src):
+            return pltpu.make_async_copy(
+                x_hbm.at[pl.ds(src, 1), pl.ds(col, c_tile)],
+                buf.at[j, pl.ds(i, 1)],
+                sem,
+            )
 
-    w = w_ref[v, d].astype(o_ref.dtype)
-    o_ref[...] = jnp.minimum(o_ref[...], x_ref[...].astype(o_ref.dtype) + w)
+        def start(i, carry):
+            for j in range(d):
+                row_copy(i, j, nbr_ref[j, i]).start()
+            return carry
+
+        def wait(i, carry):
+            for j in range(d):
+                row_copy(i, j, 0).wait()
+            return carry
+
+        jax.lax.fori_loop(0, rows, start, 0)
+        jax.lax.fori_loop(0, rows, wait, 0)
+
+        w = w_ref[...].astype(o_ref.dtype)
+        if mode == "sum":
+            acc = jnp.zeros(o_ref.shape, o_ref.dtype)
+            for j in range(d):
+                acc = acc + w[:, j:j + 1] * buf[j].astype(o_ref.dtype)
+        else:
+            acc = jnp.full(o_ref.shape, jnp.inf, o_ref.dtype)
+            for j in range(d):
+                acc = jnp.minimum(acc, buf[j].astype(o_ref.dtype) + w[:, j:j + 1])
+        o_ref[...] = acc
+
+    return kernel
 
 
 def frontier_gather(
@@ -88,29 +115,31 @@ def _frontier_gather_jit(
     interpret: bool,
 ) -> jax.Array:
     v, d = nbr.shape
-    n, c = x.shape
+    c = x.shape[1]
+    if d > MAX_SLOTS:
+        raise ValueError(f"{d} neighbor slots > {MAX_SLOTS}: cap the layout")
     c_pad = (-c) % c_tile
     if c_pad:
         x = jnp.pad(x, ((0, 0), (0, c_pad)))
-    ct = x.shape[1] // c_tile
+    v_pad = (-v) % ROWS
+    nbr_t = jnp.pad(nbr.astype(jnp.int32), ((0, v_pad), (0, 0))).T  # [D, V']
+    w = jnp.pad(w, ((0, v_pad), (0, 0)))
+    vt, ct = (v + v_pad) // ROWS, x.shape[1] // c_tile
 
-    kernel = {"sum": _frontier_sum_kernel, "min": _frontier_min_kernel}[mode]
-    # Grid order (v, ct, d): the reduction axis d must be INNERMOST — the
-    # TPU pipeline only keeps an output block resident across *consecutive*
-    # grid steps with the same out index, so accumulating over a non-final
-    # axis would read back stale VMEM whenever ct > 1.
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # nbr, w
-        grid=(v, ct, d),
-        in_specs=[
-            pl.BlockSpec((1, c_tile), lambda vv, cc, dd, nbr_, w_: (nbr_[vv, dd], cc)),
-        ],
-        out_specs=pl.BlockSpec((1, c_tile), lambda vv, cc, dd, nbr_, w_: (vv, cc)),
-    )
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((v, x.shape[1]), x.dtype),
+        _make_kernel(mode, d, ROWS),
+        grid=(vt, ct),
+        in_specs=[
+            pl.BlockSpec((d, ROWS), lambda i, cc: (0, i), memory_space=pltpu.SMEM),
+            pl.BlockSpec((ROWS, d), lambda i, cc: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((ROWS, c_tile), lambda i, cc: (i, cc)),
+        out_shape=jax.ShapeDtypeStruct((v + v_pad, x.shape[1]), x.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((d, ROWS, c_tile), x.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
         interpret=interpret,
-    )(nbr.astype(jnp.int32), w, x)
-    return out[:, :c] if c_pad else out
+    )(nbr_t, w, x)
+    return out[:v, :c]

@@ -10,9 +10,10 @@ gather kernel, and the few over-cap (COO spill) edges are combined in a
 scatter epilogue — ``scatter-add`` for ``mode="sum"``, ``scatter-min`` for
 ``mode="min"``. This is exactly the batched traffic engine's GIS layout, so
 :func:`frontier_relax` below *is* the engine's SSSP relaxation hot loop
-(:mod:`repro.core.traffic_batched` calls it every round): Pallas kernel on
-TPU, unrolled-slot XLA reference on CPU, bit-identical results either way
-(min and float32 add are exact and slot-order independent).
+(:mod:`repro.core.traffic_batched` calls it every round): the unrolled-slot
+XLA form by default, the Pallas kernel when asked for, bit-identical
+results either way (min and float32 add are exact and slot-order
+independent).
 """
 
 from __future__ import annotations
@@ -67,7 +68,7 @@ def frontier_relax(
     ``interpret`` resolved at closure-build time, as the traffic engine
     does). ``use_kernel=True`` routes the rectangular slots through the
     Pallas kernel; otherwise an unrolled-slot gather (one fused
-    gather+min per slot, the fast XLA form on CPU).
+    gather+min per slot, the faster form on CPU and on a TPU v5e).
     """
     if use_kernel:
         acc = frontier_gather(x, nbr, w_inf, mode="min", interpret=interpret)

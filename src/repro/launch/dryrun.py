@@ -75,30 +75,15 @@ def collective_stats(hlo_text: str) -> dict:
     return stats
 
 
-def _mesh_context(mesh):
-    """``jax.sharding.set_mesh`` (new API) or the Mesh's own context manager
-    (jax ≤ 0.4.x, where entering a Mesh sets the ambient mesh)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
-def _cost_dict(cost) -> dict:
-    """``Compiled.cost_analysis()`` returns a per-device list on jax ≤ 0.4.x
-    and a flat dict on newer releases; normalize to the dict."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def _compile_spec(spec, mesh):
     from repro.distributed.sharding import to_shardings
 
     in_shardings = to_shardings(mesh, spec.in_specs)
-    with _mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(spec.step_fn, in_shardings=in_shardings).lower(*spec.abstract_args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         coll = collective_stats(compiled.as_text())
     return mem, cost, coll
 

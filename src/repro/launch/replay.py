@@ -67,8 +67,10 @@ def main() -> None:
     from repro.core.traffic import execute_ops, generate_ops
     from repro.core.traffic_sharded import replay_sharded
     from repro.graphs import datasets
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_replay_mesh
 
+    enable_compile_cache()
     graph = datasets.load(args.dataset, scale=args.scale)
     ops = generate_ops(graph, n_ops=args.n_ops, seed=args.seed,
                        pattern=args.pattern)

@@ -87,6 +87,35 @@ class TestDidic:
         assert ec_damaged > ec0 * 1.5
         assert ec_repaired < ec_damaged * 0.5
 
+    @pytest.mark.parametrize("spmm_kind", ["segment", "halo"])
+    def test_step_takes_graph_tables_as_arguments(self, fs, spmm_kind):
+        """The compiled step receives the edge tables as inputs. Baked in
+        as constants they made each compiled step, and its persistent-cache
+        entry, tens of MB at paper scale."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.didic import _init_state, _make_step, make_spmm
+        from repro.core.didic_distributed import _mesh_program
+        from repro.launch.mesh import make_replay_mesh
+
+        cfg = DidicConfig(k=4, iterations=1)
+        if spmm_kind == "segment":
+            spmm, degc = make_spmm(fs, cfg)
+        else:
+            _, spmm, degc = _mesh_program(fs, make_replay_mesh(1), ("data",))
+        state = _init_state(degc.shape[0], cfg.k, jnp.zeros(degc.shape[0], jnp.int32))
+        step = _make_step(spmm, degc, cfg)
+        hlo = step.func.lower(
+            state.w, state.l, state.parts, state.beta, jax.random.PRNGKey(0),
+            jnp.int32(1), **step.keywords,
+        ).as_text()
+        edges = spmm.args[0].shape[-1]
+        assert f"{edges}x" in hlo
+        assert re.search(rf"constant.*tensor<(\d+x)?{edges}x", hlo) is None
+
 
 class TestPartitioners:
     def test_hardcoded_filesystem_subtrees(self, fs):
