@@ -1,0 +1,12 @@
+"""Device: share of the measured window in which no XLA op ran on the
+chip (1 - busy union / window), from the profiler trace of the replay cells.
+
+Source: device trace. Moves ``ops_per_s``.
+"""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
