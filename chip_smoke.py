@@ -83,32 +83,33 @@ class PhaseClock:
     ``compile_s`` is JAX's backend compile time, which includes reading an
     executable back from the persistent cache; ``cache`` counts the cache's
     lookups, hits and writes, so a warm run shows what it did not compile.
+    Both are read from the database's tracing counters
+    (:mod:`repro.core.tracing`).
     """
 
-    _CACHE_EVENTS = {
-        "/jax/compilation_cache/compile_requests_use_cache": "lookups",
-        "/jax/compilation_cache/cache_hits": "hits",
-        # JAX records a "miss" when it writes a freshly compiled entry.
-        "/jax/compilation_cache/cache_misses": "writes",
-    }
+    _CACHE = {"lookups": "jax.cache_lookups", "hits": "jax.cache_hits",
+              "writes": "jax.cache_writes"}
 
     def __init__(self, out=print):
         import jax
 
+        from repro.core import tracing
+
         self.out = out
-        self.compile_s = 0.0
-        self.cache = dict.fromkeys(self._CACHE_EVENTS.values(), 0)
+        self._tracing = tracing
+        self._c0 = tracing.snapshot()["counters"]
         self._device = jax.devices()[0]
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, name: str, secs: float, **_) -> None:
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
+    def _since_start(self, name: str) -> int:
+        return self._tracing.snapshot()["counters"].get(name, 0) - self._c0.get(name, 0)
 
-    def _on_event(self, name: str, **_) -> None:
-        if name in self._CACHE_EVENTS:
-            self.cache[self._CACHE_EVENTS[name]] += 1
+    @property
+    def compile_s(self) -> float:
+        return self._since_start("jax.compile_ns") * 1e-9
+
+    @property
+    def cache(self) -> dict:
+        return {k: self._since_start(name) for k, name in self._CACHE.items()}
 
     @contextlib.contextmanager
     def phase(self, name: str, **fields):

@@ -119,6 +119,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.framework import (
     InsertPartitioner,
     MigrationScheduler,
@@ -460,6 +461,7 @@ class DynamicExperimentRuntime:
         self._records = []
         return self._baseline
 
+    @tracing.span("slice")
     def run_slice(
         self,
         i: int,

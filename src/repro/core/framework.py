@@ -65,7 +65,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import metrics
+from repro.core import metrics, tracing
 from repro.core.didic import DidicConfig, DidicState, didic_partition, didic_refine
 from repro.core.dynamism import DynamismLog, apply_dynamism, generate_dynamism
 from repro.core.placement import Placement
@@ -633,12 +633,22 @@ class PartitionedGraphService:
                 )
             return out
 
+    def _repair(self, parts: np.ndarray, iterations: int) -> np.ndarray:
+        """DiDiC maintenance from ``parts``, until the new map is on the
+        host; counts the iterations and their sparse products."""
+        c = self.runtime.config
+        tracing.count("didic.iterations", iterations)
+        tracing.count("didic.spmms", iterations * (
+            c.primary_steps * (c.secondary_steps + 1) + c.smooth_cap))
+        with tracing.span("didic.repair"):
+            return self._maintain_attempt(
+                lambda: self.runtime.maintain(self.graph, parts,
+                                              iterations=iterations,
+                                              pinned=self.placement.hot_vertices())
+            )
+
     def maintain(self, iterations: int = 1) -> None:
-        self.parts = self._maintain_attempt(
-            lambda: self.runtime.maintain(self.graph, self.parts,
-                                          iterations=iterations,
-                                          pinned=self.placement.hot_vertices())
-        )
+        self.parts = self._repair(self.parts, iterations)
         self.logger.observe_structure(self.graph, self.parts)
 
     def propose_maintenance(self, iterations: int = 1,
@@ -653,13 +663,9 @@ class PartitionedGraphService:
         caller that may discard the proposal snapshots the state first
         and hands it to :meth:`commit_migration` for rollback.
         """
-        src = self.parts if parts is None else parts
-        return self._maintain_attempt(
-            lambda: self.runtime.maintain(self.graph, src,
-                                          iterations=iterations,
-                                          pinned=self.placement.hot_vertices())
-        )
+        return self._repair(self.parts if parts is None else parts, iterations)
 
+    @tracing.span("migrate.commit")
     def commit_migration(self, scheduler: MigrationScheduler,
                          new_parts: np.ndarray, step: int,
                          prev_state=None) -> int:
@@ -690,7 +696,9 @@ class PartitionedGraphService:
                 np.concatenate([c.vertices for c in cmds])
             )
         self.logger.observe_structure(self.graph, self.parts)
-        return int(sum(c.vertices.shape[0] for c in cmds))
+        moved = int(sum(c.vertices.shape[0] for c in cmds))
+        tracing.count("migrate.moves", moved)
+        return moved
 
     def maintain_migrate(self, scheduler: MigrationScheduler, step: int,
                          iterations: int = 1) -> int:
@@ -819,6 +827,7 @@ class PartitionedGraphService:
         return generate_ops(self.graph, n_ops=n_ops, seed=seed, pattern=pattern)
 
     # -- dynamism -----------------------------------------------------------
+    @tracing.span("dynamism.apply")
     def apply_dynamism(self, log: DynamismLog) -> None:
         """Apply a dynamism slice: partition moves, edge inserts, and —
         for vertex-growth logs — new vertices.
@@ -880,6 +889,7 @@ class PartitionedGraphService:
 
     def _apply_dynamism_checked(self, log: DynamismLog) -> None:
         """Validate-then-commit application body (journal-agnostic)."""
+        tracing.count("dynamism.moves", log.units - log.n_new_vertices)
         plan = self.fault_plan
         if not log.structural:
             new_parts = apply_dynamism(self.parts, log)

@@ -91,6 +91,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.graphs.structure import Graph, padded_neighbors
 from repro.kernels import resolve_interpret
 from repro.kernels.frontier import frontier_relax
@@ -125,6 +126,14 @@ def _capped_gather_layout(
     return pn.nbr, w_inf, pn.spill_s, pn.spill_r, pn.spill_w
 
 
+def _count_window(w_real: int, w_pad: int, full: bool) -> None:
+    """Book one packed op chunk's window to the tracing counters."""
+    tracing.count("sssp.chunks")
+    tracing.count("sssp.window_rows", w_real)
+    tracing.count("sssp.window_rows_padded", w_pad)
+    tracing.count("sssp.full_windows", int(full))
+
+
 # ===========================================================================
 # Windowed batched SSSP solve (pure function: jit caches per window shape)
 # ===========================================================================
@@ -151,7 +160,8 @@ def _sssp_solve_body(
     """Traceable solve body — shared verbatim by the single-device jit
     below and the per-shard ``shard_map`` body in
     :mod:`repro.core.traffic_sharded`, so both paths run the exact same
-    float32 operations."""
+    float32 operations. Returns ``(member, foot, edges, cross, f_dst,
+    done, rounds)``; ``rounds`` is the number of relax sweeps run."""
     w_nodes, c = h.shape
     cols = jnp.arange(c)
     inf = jnp.float32(jnp.inf)
@@ -211,7 +221,9 @@ def _sssp_solve_body(
         g, need, t, done = step(g, need, t, done)
         return g, need, t, done, rounds + 2
 
-    g, _, _, done, _ = jax.lax.while_loop(cond, body, (g0, need0, t0, done0, jnp.int32(0)))
+    g, _, _, done, rounds = jax.lax.while_loop(
+        cond, body, (g0, need0, t0, done0, jnp.int32(0))
+    )
 
     # Deterministic A* expansion set: (f, id) <_lex (f_dst, dst).
     f = g + h
@@ -240,7 +252,7 @@ def _sssp_solve_body(
     m = member.astype(jnp.int32)
     edges = (m * deg_w[:, None]).sum(axis=0)
     cross = (m * cross_w[:, None]).sum(axis=0)
-    return member, foot, edges, cross, f_dst, done
+    return member, foot, edges, cross, f_dst, done, rounds
 
 
 _sssp_solve = jax.jit(
@@ -595,29 +607,34 @@ class BatchedTrafficEngine:
         code path of the full build, so results remain bit-identical.
         """
         w_pad = self.ensure_full_layout()[0]
-        loc_src = np.where(valid, srcs, 0).astype(np.int32)
-        loc_dst = np.where(valid, dsts, 0).astype(np.int32)
-        dst_safe = np.where(valid, dsts, 0)
-        if self._device_h_ok:
-            if self._full_lonlat is None:
-                pad = np.zeros(w_pad - self.n_nodes, np.float32)
-                self._full_lonlat = (
-                    jnp.asarray(np.concatenate([self._lon, pad])),
-                    jnp.asarray(np.concatenate([self._lat, pad])),
-                )
-            h = _device_h(
-                self._full_lonlat[0], self._full_lonlat[1],
-                jnp.asarray(self._lon[dst_safe]), jnp.asarray(self._lat[dst_safe]),
-            )
-            if as_numpy:
-                h = np.asarray(h)
-        else:
-            h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
-            h[: self.n_nodes] = self._host_h(
-                np.arange(self.n_nodes, dtype=np.int64), dst_safe
-            )
+        with tracing.span("sssp.window_build"):
+            _count_window(self.n_nodes, w_pad, True)
+            loc_src = np.where(valid, srcs, 0).astype(np.int32)
+            loc_dst = np.where(valid, dsts, 0).astype(np.int32)
+            dst_safe = np.where(valid, dsts, 0)
+            with tracing.span("sssp.heuristic"):
+                if self._device_h_ok:
+                    if self._full_lonlat is None:
+                        pad = np.zeros(w_pad - self.n_nodes, np.float32)
+                        self._full_lonlat = (
+                            jnp.asarray(np.concatenate([self._lon, pad])),
+                            jnp.asarray(np.concatenate([self._lat, pad])),
+                        )
+                    h = _device_h(
+                        self._full_lonlat[0], self._full_lonlat[1],
+                        jnp.asarray(self._lon[dst_safe]), jnp.asarray(self._lat[dst_safe]),
+                    )
+                    if as_numpy:
+                        h = np.asarray(h)
+                        tracing.count("sssp.heuristic_bytes_to_host", h.nbytes)
+                else:
+                    h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
+                    h[: self.n_nodes] = self._host_h(
+                        np.arange(self.n_nodes, dtype=np.int64), dst_safe
+                    )
         return loc_src, loc_dst, dst_safe.astype(np.int32), h
 
+    @tracing.span("sssp.window_build")
     def build_sssp_problem(
         self,
         srcs: np.ndarray,
@@ -637,45 +654,49 @@ class BatchedTrafficEngine:
         the device-computed ``h`` on device. ``full`` is returned because
         a near-full window is promoted to the whole graph here.
         """
-        window, box = self._sssp_window(srcs[valid], dsts[valid], full)
-        if not full and window.shape[0] > 0.6 * self.n_nodes:
-            # Near-full window: run on the whole graph outright — cheaper
-            # than risking a second (redo) pass for rejected ops.
-            full = True
-            window, box = self._sssp_window(srcs, dsts, True)
+        with tracing.span("sssp.window_select"):
+            window, box = self._sssp_window(srcs[valid], dsts[valid], full)
+            if not full and window.shape[0] > 0.6 * self.n_nodes:
+                # Near-full window: run on the whole graph outright — cheaper
+                # than risking a second (redo) pass for rejected ops.
+                full = True
+                window, box = self._sssp_window(srcs, dsts, True)
         w_real = window.shape[0]
         if full and self._full_layout is not None:
             # The whole-graph layout is parts/ops independent — built once.
             w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w = self._full_layout
         else:
-            # Pad to a {2^k, 3·2^k} size grid: bounded jit-cache variants
-            # with ≤ 33 % padding waste (pure 2^k padding wastes up to 2×).
-            p2 = max(64, 1 << int(np.ceil(np.log2(max(w_real, 1)))))
-            w_pad = 3 * p2 // 4 if w_real <= 3 * p2 // 4 else p2
-            self._glob2loc[window] = np.arange(w_real)
-            if full:
-                es, er, ew = self.s, self.r, self.w
-            else:
-                e_mask = (self._glob2loc[self.s] >= 0) & (self._glob2loc[self.r] >= 0)
-                es, er, ew = self.s[e_mask], self.r[e_mask], self.w[e_mask]
-            nbr, w_inf, sp_s, sp_r, sp_w = _capped_gather_layout(
-                self._glob2loc[es], self._glob2loc[er], ew, w_pad, self.nbr_cap
-            )
-            s_pad = 0 if sp_s.shape[0] == 0 else max(
-                64, 1 << int(np.ceil(np.log2(sp_s.shape[0])))
-            )
-            if s_pad:
-                fill = s_pad - sp_s.shape[0]
-                sp_s = np.concatenate([sp_s, np.zeros(fill, np.int32)])
-                sp_r = np.concatenate([sp_r, np.zeros(fill, np.int32)])
-                sp_w = np.concatenate([sp_w, np.full(fill, np.inf, np.float32)])
-            ids_w = np.full(w_pad, _BIG_ID, dtype=np.int32)
-            ids_w[:w_real] = window.astype(np.int32)
-            deg_w = np.zeros(w_pad, dtype=np.int32)
-            deg_w[:w_real] = self.deg[window]
-            self._glob2loc[window] = -1  # restore the scratch map
-            if full:
-                self._full_layout = (w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w)
+            with tracing.span("sssp.gather_layout"):
+                # Pad to a {2^k, 3·2^k} size grid: bounded jit-cache variants
+                # with ≤ 33 % padding waste (pure 2^k padding wastes up to 2×).
+                p2 = max(64, 1 << int(np.ceil(np.log2(max(w_real, 1)))))
+                w_pad = 3 * p2 // 4 if w_real <= 3 * p2 // 4 else p2
+                self._glob2loc[window] = np.arange(w_real)
+                if full:
+                    es, er, ew = self.s, self.r, self.w
+                else:
+                    e_mask = (self._glob2loc[self.s] >= 0) & (self._glob2loc[self.r] >= 0)
+                    es, er, ew = self.s[e_mask], self.r[e_mask], self.w[e_mask]
+                nbr, w_inf, sp_s, sp_r, sp_w = _capped_gather_layout(
+                    self._glob2loc[es], self._glob2loc[er], ew, w_pad, self.nbr_cap
+                )
+                s_pad = 0 if sp_s.shape[0] == 0 else max(
+                    64, 1 << int(np.ceil(np.log2(sp_s.shape[0])))
+                )
+                if s_pad:
+                    fill = s_pad - sp_s.shape[0]
+                    sp_s = np.concatenate([sp_s, np.zeros(fill, np.int32)])
+                    sp_r = np.concatenate([sp_r, np.zeros(fill, np.int32)])
+                    sp_w = np.concatenate([sp_w, np.full(fill, np.inf, np.float32)])
+                ids_w = np.full(w_pad, _BIG_ID, dtype=np.int32)
+                ids_w[:w_real] = window.astype(np.int32)
+                deg_w = np.zeros(w_pad, dtype=np.int32)
+                deg_w[:w_real] = self.deg[window]
+                self._glob2loc[window] = -1  # restore the scratch map
+                if full:
+                    self._full_layout = (w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w)
+        if valid.any():
+            _count_window(w_real, w_pad, full)
 
         cross_w = np.zeros(w_pad, dtype=np.int32)
         cross_w[:w_real] = cross_deg[window]
@@ -689,20 +710,22 @@ class BatchedTrafficEngine:
             loc_dst = np.where(valid, self._glob2loc[dsts], 0).astype(np.int32)
             self._glob2loc[window] = -1  # restore the scratch map
         dst_safe = np.where(valid, dsts, 0)
-        if self._device_h_ok:
-            h = _device_h(
-                jnp.asarray(np.concatenate([self._lon[window],
-                                            np.zeros(w_pad - w_real, np.float32)])),
-                jnp.asarray(np.concatenate([self._lat[window],
-                                            np.zeros(w_pad - w_real, np.float32)])),
-                jnp.asarray(self._lon[dst_safe]),
-                jnp.asarray(self._lat[dst_safe]),
-            )
-            if as_numpy:
-                h = np.asarray(h)  # transfers are bit-preserving
-        else:
-            h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
-            h[:w_real] = self._host_h(window, dst_safe)
+        with tracing.span("sssp.heuristic"):
+            if self._device_h_ok:
+                h = _device_h(
+                    jnp.asarray(np.concatenate([self._lon[window],
+                                                np.zeros(w_pad - w_real, np.float32)])),
+                    jnp.asarray(np.concatenate([self._lat[window],
+                                                np.zeros(w_pad - w_real, np.float32)])),
+                    jnp.asarray(self._lon[dst_safe]),
+                    jnp.asarray(self._lat[dst_safe]),
+                )
+                if as_numpy:
+                    h = np.asarray(h)  # transfers are bit-preserving
+                    tracing.count("sssp.heuristic_bytes_to_host", h.nbytes)
+            else:
+                h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
+                h[:w_real] = self._host_h(window, dst_safe)
 
         args = (
             loc_src, loc_dst,
@@ -755,14 +778,19 @@ class BatchedTrafficEngine:
         args, window, w_real, box, full = self.build_sssp_problem(
             srcs, dsts, valid, cross_deg, full
         )
-        member, _foot, edges, cross, f_dst, done = _sssp_solve(
-            *(jnp.asarray(a) for a in args),
-            jnp.float32(self.delta),
-            max_expansions=self.max_expansions,
-            finite_delta=self.delta_scale is not None,
-            use_kernel=self.use_kernel,
-            interpret=self.interpret,
-        )
+        with tracing.span("sssp.stack"):
+            dev_args = [jnp.asarray(a) for a in args]
+        with tracing.span("sssp.solve"):
+            member, _foot, edges, cross, f_dst, done, rounds = jax.device_get(_sssp_solve(
+                *dev_args,
+                jnp.float32(self.delta),
+                max_expansions=self.max_expansions,
+                finite_delta=self.delta_scale is not None,
+                use_kernel=self.use_kernel,
+                interpret=self.interpret,
+            ))
+        tracing.count("sssp.op_solves", int(valid.sum()))
+        tracing.count("sssp.relax_rounds", int(rounds))
         member = np.asarray(member)
         edges = np.asarray(edges, dtype=np.int64)
         cross = np.asarray(cross, dtype=np.int64)
@@ -775,11 +803,13 @@ class BatchedTrafficEngine:
                 "batched SSSP hit its round cap before all ops settled; "
                 "raise delta_scale (or use delta_scale=None)"
             )
-        ok = self.window_accept(srcs, dsts, valid, f_dst, box, full)
+        with tracing.span("sssp.accept"):
+            ok = self.window_accept(srcs, dsts, valid, f_dst, box, full)
         return window, w_real, member, edges, cross, ok
 
     def _run_sssp(self, ops, cross_deg: np.ndarray):
-        order = self._compile_sssp_log(ops)
+        with tracing.span("sssp.order"):
+            order = self._compile_sssp_log(ops)
         n_ops = ops.n_ops
         chunk = self.chunk
         per_op_edges = np.zeros(n_ops, dtype=np.int64)
@@ -807,11 +837,14 @@ class BatchedTrafficEngine:
                         redo.append(rejected)
 
         run_pass(order, full=False)
+        tracing.count("sssp.redo_ops", sum(r.shape[0] for r in redo))
         if redo:
-            run_pass(np.concatenate(redo), full=True)
+            with tracing.span("sssp.redo"):
+                run_pass(np.concatenate(redo), full=True)
         return per_op_edges, per_op_cross, tm64
 
     # ------------------------------------------------------------------ run
+    @tracing.span("fold.cross_degree")
     def cross_degree(
         self, parts: np.ndarray, replicated: Optional[np.ndarray] = None
     ) -> np.ndarray:
@@ -823,6 +856,7 @@ class BatchedTrafficEngine:
         compiled BFS/SSSP closures consume ``cross_deg`` as a plain array
         input, so replica-awareness never retraces them.
         """
+        tracing.count("fold.edges", self.s.shape[0])
         parts = np.asarray(parts, dtype=np.int64)
         crossing = parts[self.s] != parts[self.r]
         if replicated is not None:
@@ -831,6 +865,7 @@ class BatchedTrafficEngine:
             self.s, weights=crossing, minlength=self.n_nodes
         ).astype(np.int32)
 
+    @tracing.span("fold.finalize")
     def finalize(
         self,
         edges: np.ndarray,
@@ -855,6 +890,7 @@ class BatchedTrafficEngine:
         """
         from repro.core.traffic import TrafficResult
 
+        tracing.count("fold.edges", self.s.shape[0])
         parts = np.asarray(parts, dtype=np.int64)
         deg64 = self.deg.astype(np.int64)
         pv = t_l * deg64 * tm64
@@ -891,15 +927,17 @@ class BatchedTrafficEngine:
         t_pg: int,
         replicated: Optional[np.ndarray] = None,
     ):
-        parts = np.asarray(parts, dtype=np.int64)
-        cross_deg = self.cross_degree(parts, replicated=replicated)
+        with tracing.span("replay"):
+            tracing.count("replay.ops", ops.n_ops)
+            parts = np.asarray(parts, dtype=np.int64)
+            cross_deg = self.cross_degree(parts, replicated=replicated)
 
-        if self.kind == "bfs":
-            edges, cross, tm64 = self._run_bfs(ops, cross_deg)
-        else:
-            edges, cross, tm64 = self._run_sssp(ops, cross_deg)
-        return self.finalize(edges, cross, tm64, parts, k, t_l, t_pg,
-                             replicated=replicated)
+            if self.kind == "bfs":
+                edges, cross, tm64 = self._run_bfs(ops, cross_deg)
+            else:
+                edges, cross, tm64 = self._run_sssp(ops, cross_deg)
+            return self.finalize(edges, cross, tm64, parts, k, t_l, t_pg,
+                                 replicated=replicated)
 
 
 @jax.jit

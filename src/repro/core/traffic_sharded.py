@@ -72,8 +72,9 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import tracing
 from repro.core.traffic_batched import (
     _BIG_ID,
     _sssp_solve_body,
@@ -407,7 +408,7 @@ class ShardedTrafficReplayer:
 
         def solve_body(loc_src, loc_dst, dst_ids, valid, deg_w, cross_w,
                        ids_w, nbr, w_inf, sp_s, sp_r, sp_w, h, delta):
-            member, foot, edges, cross, f_dst, done = _sssp_solve_body(
+            member, foot, edges, cross, f_dst, done, rounds = _sssp_solve_body(
                 loc_src[0], loc_dst[0], dst_ids[0], valid[0],
                 deg_w[0], cross_w[0], ids_w[0],
                 nbr[0], w_inf[0], sp_s[0], sp_r[0], sp_w[0], h[0],
@@ -418,15 +419,19 @@ class ShardedTrafficReplayer:
                 interpret=eng.interpret,
             )
             return (member[None], foot[None], edges[None], cross[None],
-                    f_dst[None], done[None])
+                    f_dst[None], done[None], rounds[None])
 
+        stack_specs = (s2, s2, s2, s2, s2, s2, s2, s3, s3, s2, s2, s2, s3)
         self._solve_fn = jax.jit(jax.shard_map(
             solve_body,
             mesh=self.mesh,
-            in_specs=(s2, s2, s2, s2, s2, s2, s2, s3, s3, s2, s2, s2, s3, P()),
-            out_specs=(s3, s3, s2, s2, s2, s2),
+            in_specs=stack_specs + (P(),),
+            out_specs=(s3, s3, s2, s2, s2, s2, P(axes)),
             check_vma=False,
         ))
+        # Stacked problems go to the device in the layout the solve reads;
+        # the stack waits for the transfer, so the solve's time is its own.
+        self._stack_shardings = tuple(NamedSharding(self.mesh, sp) for sp in stack_specs)
 
         # Redo (whole-graph) pass: the gather layout is op- and
         # parts-independent, so it is replicated once — only the per-op
@@ -435,7 +440,7 @@ class ShardedTrafficReplayer:
         def solve_full_body(loc_src, loc_dst, dst_ids, valid, h,
                             deg_w, cross_w, ids_w, nbr, w_inf,
                             sp_s, sp_r, sp_w, delta):
-            member, foot, edges, cross, f_dst, done = _sssp_solve_body(
+            member, foot, edges, cross, f_dst, done, rounds = _sssp_solve_body(
                 loc_src[0], loc_dst[0], dst_ids[0], valid[0],
                 deg_w, cross_w, ids_w, nbr, w_inf, sp_s, sp_r, sp_w, h[0],
                 delta,
@@ -445,15 +450,17 @@ class ShardedTrafficReplayer:
                 interpret=eng.interpret,
             )
             return (member[None], foot[None], edges[None], cross[None],
-                    f_dst[None], done[None])
+                    f_dst[None], done[None], rounds[None])
 
+        per_op_specs = (s2, s2, s2, s2, s3)
         self._solve_full_fn = jax.jit(jax.shard_map(
             solve_full_body,
             mesh=self.mesh,
-            in_specs=(s2, s2, s2, s2, s3) + (P(),) * 9,
-            out_specs=(s3, s3, s2, s2, s2, s2),
+            in_specs=per_op_specs + (P(),) * 9,
+            out_specs=(s3, s3, s2, s2, s2, s2, P(axes)),
             check_vma=False,
         ))
+        self._per_op_shardings = tuple(NamedSharding(self.mesh, sp) for sp in per_op_specs)
         self._full_static_dev = None
         self._scatter_psum_shared = None
 
@@ -534,6 +541,27 @@ class ShardedTrafficReplayer:
             ))
         return tuple(np.stack(col) for col in zip(*out))
 
+    @staticmethod
+    def _solve(fn, n_ops: int, *args):
+        """Run one sharded solve round until the host holds its per-op
+        results: ``(member, foot, edges, cross, f_dst)``, the masks on
+        the device. Books the round's ops and relax sweeps (summed over
+        shards) to the tracing counters."""
+        with tracing.span("sssp.solve"):
+            member, foot, edges, cross, f_dst, done, rounds = fn(*args)
+            done, edges, cross, f_dst, rounds = jax.device_get(
+                (done, edges, cross, f_dst, rounds)
+            )
+        tracing.count("sssp.op_solves", n_ops)
+        tracing.count("sssp.relax_rounds", int(np.sum(rounds, dtype=np.int64)))
+        if not np.asarray(done).all():
+            raise RuntimeError(
+                "sharded SSSP hit its round cap before all ops "
+                "settled; raise delta_scale (or use delta_scale=None)"
+            )
+        return (member, foot, np.asarray(edges, dtype=np.int64),
+                np.asarray(cross, dtype=np.int64), np.asarray(f_dst, dtype=np.float64))
+
     def _run_sssp(self, ops, cross_deg: np.ndarray,
                   state: Optional[ResidentReplayState] = None):
         eng = self.engine
@@ -544,7 +572,8 @@ class ShardedTrafficReplayer:
             # RuntimeError) after capturing some rounds; a retry must not
             # stack a second set of ok=True columns on top of them.
             state.reset()
-        order = eng._compile_sssp_log(ops)
+        with tracing.span("sssp.order"):
+            order = eng._compile_sssp_log(ops)
         n_ops, s, chunk = ops.n_ops, self.n_shards, eng.chunk
         per_op_edges = np.zeros(n_ops, dtype=np.int64)
         per_op_cross = np.zeros(n_ops, dtype=np.int64)
@@ -582,52 +611,51 @@ class ShardedTrafficReplayer:
                     probs.append(args)
                     metas.append((idx, srcs, dsts, valid, window, w_real, box, eff_full))
 
-                stacked = self._stack_problems(probs)
-                member, foot, edges, cross, f_dst, done = self._solve_fn(
-                    *stacked, jnp.float32(eng.delta)
+                with tracing.span("sssp.stack"):
+                    stacked = jax.block_until_ready(jax.device_put(
+                        self._stack_problems(probs), self._stack_shardings
+                    ))
+                member, foot, edges_h, cross_h, f_dst_h = self._solve(
+                    self._solve_fn, round_idx.shape[0], *stacked, jnp.float32(eng.delta)
                 )
-                if not np.asarray(done).all():
-                    raise RuntimeError(
-                        "sharded SSSP hit its round cap before all ops "
-                        "settled; raise delta_scale (or use delta_scale=None)"
-                    )
-                edges_h = np.asarray(edges, dtype=np.int64)
-                cross_h = np.asarray(cross, dtype=np.int64)
-                f_dst_h = np.asarray(f_dst, dtype=np.float64)
 
                 ok_all = np.zeros((s, chunk), dtype=bool)
-                for sh, (idx, srcs, dsts, valid, _w, _wr, box, eff_full) in enumerate(metas):
-                    if not idx.shape[0]:
-                        continue
-                    ok = eng.window_accept(srcs, dsts, valid, f_dst_h[sh], box, eff_full)
-                    ok_all[sh] = ok
-                    nsh = idx.shape[0]
-                    accepted = idx[ok[:nsh]]
-                    per_op_edges[accepted] = edges_h[sh, :nsh][ok[:nsh]]
-                    per_op_cross[accepted] = cross_h[sh, :nsh][ok[:nsh]]
-                    if not eff_full:
-                        rejected = idx[~ok[:nsh]]
-                        if rejected.size:
-                            redo.append(rejected)
+                with tracing.span("sssp.accept"):
+                    for sh, (idx, srcs, dsts, valid, _w, _wr, box, eff_full) in enumerate(metas):
+                        if not idx.shape[0]:
+                            continue
+                        ok = eng.window_accept(srcs, dsts, valid, f_dst_h[sh], box, eff_full)
+                        ok_all[sh] = ok
+                        nsh = idx.shape[0]
+                        accepted = idx[ok[:nsh]]
+                        per_op_edges[accepted] = edges_h[sh, :nsh][ok[:nsh]]
+                        per_op_cross[accepted] = cross_h[sh, :nsh][ok[:nsh]]
+                        if not eff_full:
+                            rejected = idx[~ok[:nsh]]
+                            if rejected.size:
+                                redo.append(rejected)
 
                 # Per-vertex mass: shard-local (member & ok) summed over
                 # ops, scattered by global window id, one psum — int32 per
                 # round (≤ S·chunk), int64 across rounds on the host.
-                mass = self._mass_fn(member, jnp.asarray(ok_all))
-                acc.add(self._scatter_psum(jnp.asarray(stacked[6]), mass))
+                with tracing.span("sssp.mass"):
+                    mass = self._mass_fn(member, jnp.asarray(ok_all))
+                    acc.add(self._scatter_psum(stacked[6], mass))
                 if state is not None:
                     state.rounds.append(_ResidentRound(
-                        ids=jnp.asarray(stacked[6]), member=member, foot=foot,
+                        ids=stacked[6], member=member, foot=foot,
                         opidx=self._round_opidx(round_idx, chunk), ok=ok_all,
                     ))
 
         run_pass(order)
         self.last_redo_ops = int(sum(r.shape[0] for r in redo))
+        tracing.count("sssp.redo_ops", self.last_redo_ops)
         if redo:
-            self._run_full_pass(
-                ops, np.concatenate(redo), cross_deg,
-                per_op_edges, per_op_cross, acc, state=state,
-            )
+            with tracing.span("sssp.redo"):
+                self._run_full_pass(
+                    ops, np.concatenate(redo), cross_deg,
+                    per_op_edges, per_op_cross, acc, state=state,
+                )
         tm = acc.total[: self.n_nodes]
         if state is not None:
             state.per_op_edges = per_op_edges
@@ -685,33 +713,31 @@ class ShardedTrafficReplayer:
                     ))
                 metas.append((idx, srcs, dsts, valid))
 
-            stacked = tuple(np.stack(col) for col in zip(*per_op))
-            member, foot, edges, cross, f_dst, done = self._solve_full_fn(
+            with tracing.span("sssp.stack"):
+                stacked = jax.block_until_ready(jax.device_put(
+                    tuple(np.stack(col) for col in zip(*per_op)), self._per_op_shardings
+                ))
+            member, foot, edges_h, cross_h, f_dst_h = self._solve(
+                self._solve_full_fn, round_idx.shape[0],
                 *stacked, deg_w_d, cross_w_d, ids_w_d, nbr_d, w_inf_d,
                 sp_s_d, sp_r_d, sp_w_d, jnp.float32(eng.delta),
             )
-            if not np.asarray(done).all():
-                raise RuntimeError(
-                    "sharded SSSP hit its round cap before all ops "
-                    "settled; raise delta_scale (or use delta_scale=None)"
-                )
-            edges_h = np.asarray(edges, dtype=np.int64)
-            cross_h = np.asarray(cross, dtype=np.int64)
-            f_dst_h = np.asarray(f_dst, dtype=np.float64)
 
             ok_all = np.zeros((s, chunk), dtype=bool)
-            for sh, (idx, srcs, dsts, valid) in enumerate(metas):
-                if not idx.shape[0]:
-                    continue
-                ok = eng.window_accept(srcs, dsts, valid, f_dst_h[sh], None, True)
-                ok_all[sh] = ok
-                nsh = idx.shape[0]
-                accepted = idx[ok[:nsh]]
-                per_op_edges[accepted] = edges_h[sh, :nsh][ok[:nsh]]
-                per_op_cross[accepted] = cross_h[sh, :nsh][ok[:nsh]]
+            with tracing.span("sssp.accept"):
+                for sh, (idx, srcs, dsts, valid) in enumerate(metas):
+                    if not idx.shape[0]:
+                        continue
+                    ok = eng.window_accept(srcs, dsts, valid, f_dst_h[sh], None, True)
+                    ok_all[sh] = ok
+                    nsh = idx.shape[0]
+                    accepted = idx[ok[:nsh]]
+                    per_op_edges[accepted] = edges_h[sh, :nsh][ok[:nsh]]
+                    per_op_cross[accepted] = cross_h[sh, :nsh][ok[:nsh]]
 
-            mass = self._mass_fn(member, jnp.asarray(ok_all))
-            acc.add(self._scatter_psum_shared(ids_w_d, mass))
+            with tracing.span("sssp.mass"):
+                mass = self._mass_fn(member, jnp.asarray(ok_all))
+                acc.add(self._scatter_psum_shared(ids_w_d, mass))
             if state is not None:
                 state.rounds.append(_ResidentRound(
                     ids=ids_w_d[None], member=member, foot=foot,
@@ -799,10 +825,11 @@ class ShardedTrafficReplayer:
         scratch_cross = np.zeros(state.n_ops, dtype=np.int64)
         n_rounds = len(state.rounds)
         try:
-            self._run_full_pass(
-                ops, idx, cross_deg, state.per_op_edges, scratch_cross, acc,
-                state=state,
-            )
+            with tracing.span("sssp.redo"):
+                self._run_full_pass(
+                    ops, idx, cross_deg, state.per_op_edges, scratch_cross, acc,
+                    state=state,
+                )
         except Exception:
             # Rounds captured before a mid-pass failure never had their
             # mass folded into tm — keeping them would double-count on a
@@ -896,15 +923,17 @@ class ShardedTrafficReplayer:
         resident solve artifacts are untouched, so the hot set can churn
         between replays without a retrace or a resident re-solve.
         """
-        parts = np.asarray(parts, dtype=np.int64)
-        cross_deg = self.engine.cross_degree(parts, replicated=replicated)
-        state = self._resident_state(ops) if resident else None
-        if self.engine.kind == "bfs":
-            edges, cross, tm64 = self._run_bfs(ops, cross_deg, state)
-        else:
-            edges, cross, tm64 = self._run_sssp(ops, cross_deg, state)
-        return self.engine.finalize(edges, cross, tm64, parts, k, ops.t_l, ops.t_pg,
-                                    replicated=replicated)
+        with tracing.span("replay"):
+            tracing.count("replay.ops", ops.n_ops)
+            parts = np.asarray(parts, dtype=np.int64)
+            cross_deg = self.engine.cross_degree(parts, replicated=replicated)
+            state = self._resident_state(ops) if resident else None
+            if self.engine.kind == "bfs":
+                edges, cross, tm64 = self._run_bfs(ops, cross_deg, state)
+            else:
+                edges, cross, tm64 = self._run_sssp(ops, cross_deg, state)
+            return self.engine.finalize(edges, cross, tm64, parts, k, ops.t_l, ops.t_pg,
+                                        replicated=replicated)
 
 
 def get_replayer(
