@@ -60,10 +60,15 @@ Two locality levers make this fast rather than merely correct:
 The traffic accounting set is the deterministically defined A* expansion
 set (see :mod:`repro.core.traffic`): membership and tie-breaks are decided
 from final float32 distances computed with the same operation order as the
-scalar oracle (on-device heuristic rows are used only after an engine-init
-probe proves XLA reproduces NumPy's float32 rounding bit-for-bit — FMA
-fusion would silently break equivalence), so the engines agree
-**bit-for-bit** on every counter.
+scalar oracle, so the engines agree **bit-for-bit** on every counter. The
+heuristic rows ``h = sqrt(dx·dx + dy·dy)`` are computed inside the solve
+from the window's and the destinations' coordinates, never as a ``[W, C]``
+input, and are NumPy's float32 on every backend: the squares are kept out
+of a fused multiply-add, and a ``sqrt`` that is a few ulps off (a TPU's)
+is corrected by an exact integer test of the neighbouring floats
+(:func:`_round_sqrt`; the counter ``sssp.heuristic_corrected`` says how
+often). An engine-init probe checks the compiled rows against NumPy's and
+raises on a mismatch.
 
 All jitted closures and packed layouts are cached on the graph object
 (lifetime-tied, as in :mod:`repro.core.didic`) — or, for a growing graph
@@ -135,6 +140,76 @@ def _count_window(w_real: int, w_pad: int, full: bool) -> None:
 
 
 # ===========================================================================
+# Euclidean heuristic rows, bit-equal to NumPy's float32 on every backend
+# ===========================================================================
+def _above_upper_mid(x_bits, t_bits):
+    """``x > m²`` exactly, in int32 arithmetic, where ``m`` is the midpoint
+    between the positive normal float32 ``t`` and the next float32 up.
+
+    With ``t = M·2^e`` (``M`` the 24-bit significand), ``m = (2M+1)·2^(e−1)``
+    and ``x = X·2^ex`` lies above ``m²`` iff ``X·2^j > ⌊M(M+1)/2^22⌋`` with
+    ``j = ex − 2e − 22`` (``m²`` is never a float32, so there is no tie).
+    For ``j`` in 0..2 both sides are integers below 2^26; ``j ≥ 3`` is
+    always above and ``j < 0`` never. ``M(M+1)`` (48 bits) is assembled
+    from the 12-bit halves of ``M`` so that no product exceeds 2^25.
+    """
+    m = (t_bits & 0x7FFFFF) | 0x800000
+    mh, ml = m >> 12, m & 0xFFF
+    p = 2 * mh * ml
+    n22 = 4 * mh * mh + (p >> 10) + ((((p & 0x3FF) << 12) + ml * ml + m) >> 22)
+    j = (x_bits >> 23) - 2 * (t_bits >> 23) + 128  # biased exponents
+    mx = (x_bits & 0x7FFFFF) | 0x800000
+    return (j >= 3) | ((j >= 0) & ((mx << jnp.clip(j, 0, 2)) > n22))
+
+
+# Widest error of a backend's float32 ``sqrt``, in ulps, that the rounding
+# step corrects. A TPU v5e's is off by up to 3 ulps, in 40 % of all float32
+# values of [2⁻⁴⁰, 2⁸) (measured on the chip); XLA's CPU ``sqrt`` by none.
+_SQRT_ULPS = 4
+
+
+def _round_sqrt(x, s):
+    """The correctly rounded float32 ``sqrt(x)`` (what NumPy returns), from
+    ``s`` within ``_SQRT_ULPS`` ulps of it.
+
+    Each candidate ``c`` from ``s − K`` to ``s + K − 1`` ulps (a positive
+    float's bit pattern ± 1 is its ``nextafter``) is tested exactly: ``x``
+    lies above the square of the midpoint between ``c`` and the next float
+    up iff ``c`` is below the correctly rounded root, so the root is
+    ``s − K`` plus the number of such candidates. Where ``sqrt`` already
+    rounds correctly, as XLA's does on the CPU, the result is ``s``.
+
+    ``x`` is ``dx² + dy²`` of map coordinate differences: zero, or far
+    above the float32 subnormals (two distinct Romanian longitudes, near
+    20–30°, differ by at least 2⁻¹⁹, so ``x ≥ 2⁻³⁸``; subnormals start at
+    2⁻¹²⁶). A backend that flushes subnormals to zero therefore cannot
+    change the result."""
+    xb = jax.lax.bitcast_convert_type(x, jnp.int32)
+    lo = jax.lax.bitcast_convert_type(s, jnp.int32) - _SQRT_ULPS
+    below = sum(_above_upper_mid(xb, lo + i).astype(jnp.int32)
+                for i in range(2 * _SQRT_ULPS))
+    return jnp.where(x > 0, jax.lax.bitcast_convert_type(lo + below, jnp.float32),
+                     jnp.float32(0))
+
+
+def _heuristic_rows(lon_w, lat_w, dst_lon, dst_lat):
+    """``[W, C]`` Euclidean heuristic ``sqrt(dx·dx + dy·dy)`` from every
+    window row to every op's destination, bit-equal to NumPy's float32
+    (:meth:`BatchedTrafficEngine._host_h`), and the ``[W, C]`` mask of the
+    entries where the backend's own ``sqrt`` was off."""
+    dx = lon_w[:, None] - dst_lon[None, :]
+    dy = lat_w[:, None] - dst_lat[None, :]
+    # NumPy rounds each square and then the sum. ``maximum(·, 0)``, the
+    # identity on a square, keeps the compiler from contracting a square
+    # and the sum into one fused multiply-add, which rounds once (XLA's CPU
+    # backend does so, and then differs from NumPy in one entry of ten).
+    x = jnp.maximum(dx * dx, 0.0) + jnp.maximum(dy * dy, 0.0)
+    s = jnp.sqrt(x)
+    h = _round_sqrt(x, s)
+    return h, h != s
+
+
+# ===========================================================================
 # Windowed batched SSSP solve (pure function: jit caches per window shape)
 # ===========================================================================
 def _sssp_solve_body(
@@ -150,7 +225,10 @@ def _sssp_solve_body(
     spill_s,       # [S] int32 local senders of over-cap edges (0 padded)
     spill_r,       # [S] int32 local receivers of over-cap edges
     spill_w,       # [S] float32 weights (+inf where padded)
-    h,             # [W, C] float32 Euclidean heuristic to each op's dst
+    lon_w,         # [W] float32 window rows' coordinates (0 padded)
+    lat_w,         # [W] float32
+    dst_lon,       # [C] float32 each op's destination coordinates
+    dst_lat,       # [C] float32
     delta,         # f32 scalar bucket width (ignored unless finite_delta)
     max_expansions: int,
     finite_delta: bool,
@@ -161,8 +239,12 @@ def _sssp_solve_body(
     below and the per-shard ``shard_map`` body in
     :mod:`repro.core.traffic_sharded`, so both paths run the exact same
     float32 operations. Returns ``(member, foot, edges, cross, f_dst,
-    done, rounds)``; ``rounds`` is the number of relax sweeps run."""
-    w_nodes, c = h.shape
+    done, rounds, corrected)``; ``rounds`` is the number of relax sweeps
+    run, ``corrected`` the number of heuristic entries (real rows, valid
+    ops) where the backend's ``sqrt`` needed the rounding step. The
+    heuristic rows are computed here, where ``f`` needs them, from the
+    ``[W]`` and ``[C]`` coordinates: no ``[W, C]`` array is an input."""
+    w_nodes, c = lon_w.shape[0], starts.shape[0]
     cols = jnp.arange(c)
     inf = jnp.float32(jnp.inf)
     max_rounds = 4 * w_nodes + 16
@@ -226,6 +308,8 @@ def _sssp_solve_body(
     )
 
     # Deterministic A* expansion set: (f, id) <_lex (f_dst, dst).
+    h, moved = _heuristic_rows(lon_w, lat_w, dst_lon, dst_lat)
+    corrected = (moved & (ids_w < _BIG_ID)[:, None] & valid[None, :]).sum(dtype=jnp.int32)
     f = g + h
     f_dst = f[ends, cols]
     member = (f < f_dst[None, :]) | (
@@ -252,7 +336,7 @@ def _sssp_solve_body(
     m = member.astype(jnp.int32)
     edges = (m * deg_w[:, None]).sum(axis=0)
     cross = (m * cross_w[:, None]).sum(axis=0)
-    return member, foot, edges, cross, f_dst, done, rounds
+    return member, foot, edges, cross, f_dst, done, rounds, corrected
 
 
 _sssp_solve = jax.jit(
@@ -321,12 +405,11 @@ class BatchedTrafficEngine:
             self.chunk = chunk or 128
             self.delta_scale = delta_scale
             self._full_layout = None
-            self._full_lonlat = None
             self.nbr_cap = None  # frozen on first structure load below
 
         self._load_structure(graph)
         if self.kind == "sssp":
-            self._device_h_ok = self._check_device_h()
+            self._check_device_h()
 
     def _load_structure(self, graph: Graph) -> None:
         """(Re)load host truth + capacity-padded device buffers from
@@ -370,7 +453,6 @@ class BatchedTrafficEngine:
                 )
             self._glob2loc = np.full(self.n_nodes, -1, dtype=np.int64)
             self._full_layout = None
-            self._full_lonlat = None
         else:
             if self._e_cap is not None:
                 if self.s.shape[0] > self._e_cap:
@@ -524,15 +606,23 @@ class BatchedTrafficEngine:
         )
 
     # ====================================================== GIS batched SSSP
-    def _check_device_h(self) -> bool:
+    def _check_device_h(self) -> None:
+        """Init probe: the solve's heuristic rows, compiled for this
+        backend, against NumPy's on this graph's coordinates. There is no
+        other path to fall back on, so a mismatch is an error."""
         probe = np.arange(min(self.n_nodes, 64), dtype=np.int64)
         window = np.arange(min(self.n_nodes, 4096), dtype=np.int64)
         host = self._host_h(window, probe)
         dev = np.asarray(
             _device_h(jnp.asarray(self._lon[window]), jnp.asarray(self._lat[window]),
-                      jnp.asarray(self._lon[probe]), jnp.asarray(self._lat[probe]))
+                      jnp.asarray(self._lon[probe]), jnp.asarray(self._lat[probe]))[0]
         )
-        return bool(np.array_equal(host, dev))
+        bad = int(np.sum(host.view(np.int32) != dev.view(np.int32)))
+        if bad:
+            raise RuntimeError(
+                f"the {jax.default_backend()} backend's heuristic rows differ from "
+                f"NumPy's float32 in {bad} of {host.size} probe entries"
+            )
 
     def _host_h(self, window: np.ndarray, ends: np.ndarray) -> np.ndarray:
         dx = self._lon[window][:, None] - self._lon[ends][None, :]
@@ -581,7 +671,7 @@ class BatchedTrafficEngine:
 
     def ensure_full_layout(self):
         """Whole-graph gather layout ``(w_pad, nbr, w_inf, sp_s, sp_r,
-        sp_w, ids_w, deg_w)`` — parts/ops independent, built once and
+        sp_w, ids_w, deg_w, lon_w, lat_w)`` — parts/ops independent, built once and
         shared by the single-device redo pass and the sharded replayer's
         replicated device-resident copy."""
         if self._full_layout is None:
@@ -591,20 +681,12 @@ class BatchedTrafficEngine:
             )
         return self._full_layout
 
-    def full_per_op(
-        self,
-        srcs: np.ndarray,
-        dsts: np.ndarray,
-        valid: np.ndarray,
-        as_numpy: bool = False,
-    ):
-        """Per-op columns ``(loc_src, loc_dst, dst_ids, h)`` for the
-        whole-graph window — the slim form of
+    def full_per_op(self, srcs: np.ndarray, dsts: np.ndarray, valid: np.ndarray):
+        """Per-op columns ``(loc_src, loc_dst, dst_ids, dst_lon, dst_lat)``
+        for the whole-graph window — the slim form of
         ``build_sssp_problem(full=True)`` for callers that already hold
         the shared layout (:meth:`ensure_full_layout`): no O(N) window
-        enumeration or cross_w rebuild per chunk, and the padded window
-        coordinates stay device-resident. ``h`` is computed by the exact
-        code path of the full build, so results remain bit-identical.
+        enumeration or cross_w rebuild per chunk.
         """
         w_pad = self.ensure_full_layout()[0]
         with tracing.span("sssp.window_build"):
@@ -613,26 +695,8 @@ class BatchedTrafficEngine:
             loc_dst = np.where(valid, dsts, 0).astype(np.int32)
             dst_safe = np.where(valid, dsts, 0)
             with tracing.span("sssp.heuristic"):
-                if self._device_h_ok:
-                    if self._full_lonlat is None:
-                        pad = np.zeros(w_pad - self.n_nodes, np.float32)
-                        self._full_lonlat = (
-                            jnp.asarray(np.concatenate([self._lon, pad])),
-                            jnp.asarray(np.concatenate([self._lat, pad])),
-                        )
-                    h = _device_h(
-                        self._full_lonlat[0], self._full_lonlat[1],
-                        jnp.asarray(self._lon[dst_safe]), jnp.asarray(self._lat[dst_safe]),
-                    )
-                    if as_numpy:
-                        h = np.asarray(h)
-                        tracing.count("sssp.heuristic_bytes_to_host", h.nbytes)
-                else:
-                    h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
-                    h[: self.n_nodes] = self._host_h(
-                        np.arange(self.n_nodes, dtype=np.int64), dst_safe
-                    )
-        return loc_src, loc_dst, dst_safe.astype(np.int32), h
+                dst_lon, dst_lat = self._lon[dst_safe], self._lat[dst_safe]
+        return loc_src, loc_dst, dst_safe.astype(np.int32), dst_lon, dst_lat
 
     @tracing.span("sssp.window_build")
     def build_sssp_problem(
@@ -642,17 +706,14 @@ class BatchedTrafficEngine:
         valid: np.ndarray,
         cross_deg: np.ndarray,
         full: bool,
-        as_numpy: bool = False,
     ):
         """Host-side packing of one op chunk into a solver problem.
 
         Returns ``(args, window, w_real, box, full)`` where ``args`` is the
         positional-argument tuple of :func:`_sssp_solve_body` up to and
-        including ``h`` (everything shape-dependent). ``as_numpy=True``
-        forces the heuristic rows back to host (the sharded replayer
-        stacks problems across mesh shards); the single-device path keeps
-        the device-computed ``h`` on device. ``full`` is returned because
-        a near-full window is promoted to the whole graph here.
+        including ``dst_lat`` (everything shape-dependent), all host
+        arrays. ``full`` is returned because a near-full window is
+        promoted to the whole graph here.
         """
         with tracing.span("sssp.window_select"):
             window, box = self._sssp_window(srcs[valid], dsts[valid], full)
@@ -664,7 +725,7 @@ class BatchedTrafficEngine:
         w_real = window.shape[0]
         if full and self._full_layout is not None:
             # The whole-graph layout is parts/ops independent — built once.
-            w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w = self._full_layout
+            w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w, lon_w, lat_w = self._full_layout
         else:
             with tracing.span("sssp.gather_layout"):
                 # Pad to a {2^k, 3·2^k} size grid: bounded jit-cache variants
@@ -692,9 +753,14 @@ class BatchedTrafficEngine:
                 ids_w[:w_real] = window.astype(np.int32)
                 deg_w = np.zeros(w_pad, dtype=np.int32)
                 deg_w[:w_real] = self.deg[window]
+                lon_w = np.zeros(w_pad, dtype=np.float32)
+                lon_w[:w_real] = self._lon[window]
+                lat_w = np.zeros(w_pad, dtype=np.float32)
+                lat_w[:w_real] = self._lat[window]
                 self._glob2loc[window] = -1  # restore the scratch map
                 if full:
-                    self._full_layout = (w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w)
+                    self._full_layout = (w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w,
+                                         lon_w, lat_w)
         if valid.any():
             _count_window(w_real, w_pad, full)
 
@@ -711,27 +777,12 @@ class BatchedTrafficEngine:
             self._glob2loc[window] = -1  # restore the scratch map
         dst_safe = np.where(valid, dsts, 0)
         with tracing.span("sssp.heuristic"):
-            if self._device_h_ok:
-                h = _device_h(
-                    jnp.asarray(np.concatenate([self._lon[window],
-                                                np.zeros(w_pad - w_real, np.float32)])),
-                    jnp.asarray(np.concatenate([self._lat[window],
-                                                np.zeros(w_pad - w_real, np.float32)])),
-                    jnp.asarray(self._lon[dst_safe]),
-                    jnp.asarray(self._lat[dst_safe]),
-                )
-                if as_numpy:
-                    h = np.asarray(h)  # transfers are bit-preserving
-                    tracing.count("sssp.heuristic_bytes_to_host", h.nbytes)
-            else:
-                h = np.zeros((w_pad, srcs.shape[0]), dtype=np.float32)
-                h[:w_real] = self._host_h(window, dst_safe)
+            dst_lon, dst_lat = self._lon[dst_safe], self._lat[dst_safe]
 
         args = (
-            loc_src, loc_dst,
-            np.where(valid, dsts, 0).astype(np.int32),
+            loc_src, loc_dst, dst_safe.astype(np.int32),
             valid, deg_w, cross_w, ids_w,
-            nbr, w_inf, sp_s, sp_r, sp_w, h,
+            nbr, w_inf, sp_s, sp_r, sp_w, lon_w, lat_w, dst_lon, dst_lat,
         )
         return args, window, w_real, box, full
 
@@ -781,7 +832,7 @@ class BatchedTrafficEngine:
         with tracing.span("sssp.stack"):
             dev_args = [jnp.asarray(a) for a in args]
         with tracing.span("sssp.solve"):
-            member, _foot, edges, cross, f_dst, done, rounds = jax.device_get(_sssp_solve(
+            member, _foot, edges, cross, f_dst, done, rounds, corrected = jax.device_get(_sssp_solve(
                 *dev_args,
                 jnp.float32(self.delta),
                 max_expansions=self.max_expansions,
@@ -791,6 +842,7 @@ class BatchedTrafficEngine:
             ))
         tracing.count("sssp.op_solves", int(valid.sum()))
         tracing.count("sssp.relax_rounds", int(rounds))
+        tracing.count("sssp.heuristic_corrected", int(corrected))
         member = np.asarray(member)
         edges = np.asarray(edges, dtype=np.int64)
         cross = np.asarray(cross, dtype=np.int64)
@@ -942,11 +994,10 @@ class BatchedTrafficEngine:
 
 @jax.jit
 def _device_h(lon_w, lat_w, dst_lon, dst_lat):
-    """[W, C] heuristic rows on device (only used when bit-identical to
-    NumPy — see BatchedTrafficEngine._check_device_h)."""
-    dx = lon_w[:, None] - dst_lon[None, :]
-    dy = lat_w[:, None] - dst_lat[None, :]
-    return jnp.sqrt(dx * dx + dy * dy)
+    """The solve's heuristic rows and rounding mask in a program of their
+    own, for the engine-init probe (:meth:`BatchedTrafficEngine._check_device_h`)
+    only: a replay computes them inside the solve."""
+    return _heuristic_rows(lon_w, lat_w, dst_lon, dst_lat)
 
 
 def get_engine(

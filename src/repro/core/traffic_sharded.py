@@ -27,7 +27,7 @@ folds waves into int64 on the host.
 **Windowed batched SSSP (GIS).** Each round hands every data shard one
 chunk of ops in the engine's difficulty order, packed by the engine's own
 :meth:`~repro.core.traffic_batched.BatchedTrafficEngine.build_sssp_problem`
-(windows, capped gather layout, verified heuristic rows) and padded to
+(windows, capped gather layout, coordinates) and padded to
 common shapes. The per-shard solve is literally
 :func:`~repro.core.traffic_batched._sssp_solve_body` — the same float32
 operations as the single-device engine, so distances (and therefore the
@@ -407,57 +407,59 @@ class ShardedTrafficReplayer:
         s3 = P(axes, None, None)
 
         def solve_body(loc_src, loc_dst, dst_ids, valid, deg_w, cross_w,
-                       ids_w, nbr, w_inf, sp_s, sp_r, sp_w, h, delta):
-            member, foot, edges, cross, f_dst, done, rounds = _sssp_solve_body(
+                       ids_w, nbr, w_inf, sp_s, sp_r, sp_w,
+                       lon_w, lat_w, dst_lon, dst_lat, delta):
+            out = _sssp_solve_body(
                 loc_src[0], loc_dst[0], dst_ids[0], valid[0],
                 deg_w[0], cross_w[0], ids_w[0],
-                nbr[0], w_inf[0], sp_s[0], sp_r[0], sp_w[0], h[0],
+                nbr[0], w_inf[0], sp_s[0], sp_r[0], sp_w[0],
+                lon_w[0], lat_w[0], dst_lon[0], dst_lat[0],
                 delta,
                 max_expansions=eng.max_expansions,
                 finite_delta=eng.delta_scale is not None,
                 use_kernel=eng.use_kernel,
                 interpret=eng.interpret,
             )
-            return (member[None], foot[None], edges[None], cross[None],
-                    f_dst[None], done[None], rounds[None])
+            return tuple(a[None] for a in out)
 
-        stack_specs = (s2, s2, s2, s2, s2, s2, s2, s3, s3, s2, s2, s2, s3)
+        out_specs = (s3, s3, s2, s2, s2, s2, P(axes), P(axes))
+        stack_specs = (s2, s2, s2, s2, s2, s2, s2, s3, s3, s2, s2, s2, s2, s2, s2, s2)
         self._solve_fn = jax.jit(jax.shard_map(
             solve_body,
             mesh=self.mesh,
             in_specs=stack_specs + (P(),),
-            out_specs=(s3, s3, s2, s2, s2, s2, P(axes)),
+            out_specs=out_specs,
             check_vma=False,
         ))
         # Stacked problems go to the device in the layout the solve reads;
         # the stack waits for the transfer, so the solve's time is its own.
         self._stack_shardings = tuple(NamedSharding(self.mesh, sp) for sp in stack_specs)
 
-        # Redo (whole-graph) pass: the gather layout is op- and
-        # parts-independent, so it is replicated once — only the per-op
-        # columns (src/dst/valid/heuristic rows) are data-sharded. The old
-        # path restacked the full layout once per shard per round.
-        def solve_full_body(loc_src, loc_dst, dst_ids, valid, h,
+        # Redo (whole-graph) pass: the gather layout and the coordinates
+        # are op- and parts-independent, so they are replicated once — only
+        # the per-op columns (src/dst/valid/destination coordinates) are
+        # data-sharded.
+        def solve_full_body(loc_src, loc_dst, dst_ids, valid, dst_lon, dst_lat,
                             deg_w, cross_w, ids_w, nbr, w_inf,
-                            sp_s, sp_r, sp_w, delta):
-            member, foot, edges, cross, f_dst, done, rounds = _sssp_solve_body(
+                            sp_s, sp_r, sp_w, lon_w, lat_w, delta):
+            out = _sssp_solve_body(
                 loc_src[0], loc_dst[0], dst_ids[0], valid[0],
-                deg_w, cross_w, ids_w, nbr, w_inf, sp_s, sp_r, sp_w, h[0],
+                deg_w, cross_w, ids_w, nbr, w_inf, sp_s, sp_r, sp_w,
+                lon_w, lat_w, dst_lon[0], dst_lat[0],
                 delta,
                 max_expansions=eng.max_expansions,
                 finite_delta=eng.delta_scale is not None,
                 use_kernel=eng.use_kernel,
                 interpret=eng.interpret,
             )
-            return (member[None], foot[None], edges[None], cross[None],
-                    f_dst[None], done[None], rounds[None])
+            return tuple(a[None] for a in out)
 
-        per_op_specs = (s2, s2, s2, s2, s3)
+        per_op_specs = (s2,) * 6
         self._solve_full_fn = jax.jit(jax.shard_map(
             solve_full_body,
             mesh=self.mesh,
-            in_specs=per_op_specs + (P(),) * 9,
-            out_specs=(s3, s3, s2, s2, s2, s2, P(axes)),
+            in_specs=per_op_specs + (P(),) * 11,
+            out_specs=out_specs,
             check_vma=False,
         ))
         self._per_op_shardings = tuple(NamedSharding(self.mesh, sp) for sp in per_op_specs)
@@ -499,14 +501,12 @@ class ShardedTrafficReplayer:
     def _full_static(self):
         """Device-resident replicated whole-graph layout (built once)."""
         if self._full_static_dev is None:
-            w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w = (
+            w_pad, nbr, w_inf, sp_s, sp_r, sp_w, ids_w, deg_w, lon_w, lat_w = (
                 self.engine.ensure_full_layout()
             )
-            self._full_static_dev = (
-                w_pad,
-                jnp.asarray(deg_w), jnp.asarray(ids_w),
-                jnp.asarray(nbr), jnp.asarray(w_inf),
-                jnp.asarray(sp_s), jnp.asarray(sp_r), jnp.asarray(sp_w),
+            self._full_static_dev = (w_pad,) + tuple(
+                jnp.asarray(a) for a in (deg_w, ids_w, nbr, w_inf, sp_s, sp_r, sp_w,
+                                         lon_w, lat_w)
             )
             if self._scatter_psum_shared is None:
                 self._scatter_psum_shared = make_scatter_psum(
@@ -519,17 +519,14 @@ class ShardedTrafficReplayer:
         w_pad = max(p[7].shape[0] for p in probs)   # nbr rows
         d = max(p[7].shape[1] for p in probs)       # nbr slots
         sp = max(p[9].shape[0] for p in probs)      # spill length
-        c = probs[0][0].shape[0]
         out = []
         for (loc_src, loc_dst, dst_ids, valid, deg_w, cross_w, ids_w,
-             nbr, w_inf, sp_s, sp_r, sp_w, h) in probs:
+             nbr, w_inf, sp_s, sp_r, sp_w, lon_w, lat_w, dst_lon, dst_lat) in probs:
             wr = nbr.shape[0]
             nbr_p = np.zeros((w_pad, d), np.int32)
             nbr_p[:wr, : nbr.shape[1]] = nbr
             w_inf_p = np.full((w_pad, d), np.inf, np.float32)
             w_inf_p[:wr, : w_inf.shape[1]] = w_inf
-            h_p = np.zeros((w_pad, c), np.float32)
-            h_p[:wr] = h
             out.append((
                 loc_src, loc_dst, dst_ids, valid,
                 _pad_to(deg_w, w_pad, 0), _pad_to(cross_w, w_pad, 0),
@@ -537,7 +534,7 @@ class ShardedTrafficReplayer:
                 nbr_p, w_inf_p,
                 _pad_to(sp_s, sp, 0), _pad_to(sp_r, sp, 0),
                 _pad_to(sp_w, sp, np.float32(np.inf)),
-                h_p,
+                _pad_to(lon_w, w_pad, 0), _pad_to(lat_w, w_pad, 0), dst_lon, dst_lat,
             ))
         return tuple(np.stack(col) for col in zip(*out))
 
@@ -545,15 +542,16 @@ class ShardedTrafficReplayer:
     def _solve(fn, n_ops: int, *args):
         """Run one sharded solve round until the host holds its per-op
         results: ``(member, foot, edges, cross, f_dst)``, the masks on
-        the device. Books the round's ops and relax sweeps (summed over
-        shards) to the tracing counters."""
+        the device. Books the round's ops, relax sweeps and corrected
+        heuristic entries (summed over shards) to the tracing counters."""
         with tracing.span("sssp.solve"):
-            member, foot, edges, cross, f_dst, done, rounds = fn(*args)
-            done, edges, cross, f_dst, rounds = jax.device_get(
-                (done, edges, cross, f_dst, rounds)
+            member, foot, edges, cross, f_dst, done, rounds, corrected = fn(*args)
+            done, edges, cross, f_dst, rounds, corrected = jax.device_get(
+                (done, edges, cross, f_dst, rounds, corrected)
             )
         tracing.count("sssp.op_solves", n_ops)
         tracing.count("sssp.relax_rounds", int(np.sum(rounds, dtype=np.int64)))
+        tracing.count("sssp.heuristic_corrected", int(np.sum(corrected, dtype=np.int64)))
         if not np.asarray(done).all():
             raise RuntimeError(
                 "sharded SSSP hit its round cap before all ops "
@@ -591,7 +589,7 @@ class ShardedTrafficReplayer:
                     valid = _pad_to(np.ones(idx.shape[0], bool), chunk, False)
                     if idx.shape[0]:
                         args, window, w_real, box, eff_full = eng.build_sssp_problem(
-                            srcs, dsts, valid, cross_deg, False, as_numpy=True
+                            srcs, dsts, valid, cross_deg, False
                         )
                     else:
                         # Idle shard this round: an inert all-invalid
@@ -605,7 +603,8 @@ class ShardedTrafficReplayer:
                             np.full((1, 1), np.inf, np.float32),
                             np.zeros(0, np.int32), np.zeros(0, np.int32),
                             np.zeros(0, np.float32),
-                            np.zeros((1, chunk), np.float32),
+                            np.zeros(1, np.float32), np.zeros(1, np.float32),
+                            np.zeros(chunk, np.float32), np.zeros(chunk, np.float32),
                         )
                         window, w_real, box, eff_full = None, 0, None, False
                     probs.append(args)
@@ -686,9 +685,8 @@ class ShardedTrafficReplayer:
         replicated-ids round.
         """
         eng, s, chunk = self.engine, self.n_shards, self.engine.chunk
-        w_pad, deg_w_d, ids_w_d, nbr_d, w_inf_d, sp_s_d, sp_r_d, sp_w_d = (
-            self._full_static()
-        )
+        (w_pad, deg_w_d, ids_w_d, nbr_d, w_inf_d, sp_s_d, sp_r_d, sp_w_d,
+         lon_w_d, lat_w_d) = self._full_static()
         cross_w = np.zeros(w_pad, dtype=np.int32)
         cross_w[: self.n_nodes] = cross_deg
         cross_w_d = jnp.asarray(cross_w)
@@ -701,15 +699,15 @@ class ShardedTrafficReplayer:
                 dsts = _pad_to(ops.ends[idx], chunk, 0)
                 valid = _pad_to(np.ones(idx.shape[0], bool), chunk, False)
                 if idx.shape[0]:
-                    loc_src, loc_dst, dst_ids, h = eng.full_per_op(
-                        srcs, dsts, valid, as_numpy=True
+                    loc_src, loc_dst, dst_ids, dst_lon, dst_lat = eng.full_per_op(
+                        srcs, dsts, valid
                     )
-                    per_op.append((loc_src, loc_dst, dst_ids, valid, h))
+                    per_op.append((loc_src, loc_dst, dst_ids, valid, dst_lon, dst_lat))
                 else:
                     per_op.append((
                         np.zeros(chunk, np.int32), np.zeros(chunk, np.int32),
                         np.zeros(chunk, np.int32), valid,
-                        np.zeros((w_pad, chunk), np.float32),
+                        np.zeros(chunk, np.float32), np.zeros(chunk, np.float32),
                     ))
                 metas.append((idx, srcs, dsts, valid))
 
@@ -720,7 +718,7 @@ class ShardedTrafficReplayer:
             member, foot, edges_h, cross_h, f_dst_h = self._solve(
                 self._solve_full_fn, round_idx.shape[0],
                 *stacked, deg_w_d, cross_w_d, ids_w_d, nbr_d, w_inf_d,
-                sp_s_d, sp_r_d, sp_w_d, jnp.float32(eng.delta),
+                sp_s_d, sp_r_d, sp_w_d, lon_w_d, lat_w_d, jnp.float32(eng.delta),
             )
 
             ok_all = np.zeros((s, chunk), dtype=bool)

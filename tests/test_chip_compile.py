@@ -10,7 +10,8 @@ Shapes are those of the GIS engine at the paper's ``scale=1.0``
 (785,891 vertices, 8 neighbor slots, 128-op chunks), read off
 ``BatchedTrafficEngine.build_sssp_problem`` and ``ensure_full_layout``:
 a windowed chunk pads to 524,288 rows, the whole-graph redo layout to
-786,432.
+786,432. The sharded whole-graph solve, heuristic rows included, is
+compiled there too.
 
 The topology is described inside a module fixture: only the process that
 runs these tests loads the TPU library, and every worker collects the
@@ -94,3 +95,35 @@ def test_bsr_spmm_compiles_within_smem(one_chip):
         interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_whole_graph_solve_compiles_at_gis_paper_scale(topo):
+    """``jit_solve_full_body`` on a one-chip mesh at 786,432 rows × 128 ops:
+    the heuristic rows are computed inside it (the ±1 ulp rounding step
+    on the ``sqrt``'s bit pattern and the integer midpoint check) from
+    ``[W]`` and ``[C]`` coordinates, and the whole program fits in HBM."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.traffic_sharded import ShardedTrafficReplayer
+    from repro.graphs import datasets
+
+    rows = GIS_ROWS["whole_graph"]
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    rep = ShardedTrafficReplayer(datasets.load("gis", scale=0.002), "gis_short", mesh)
+    op, rep_ = NamedSharding(mesh, P("data", None)), NamedSharding(mesh, P())
+    i32, f32 = jnp.int32, jnp.float32
+    per_op = [_shape(op, (1, C), t) for t in (i32, i32, i32, jnp.bool_, f32, f32)]
+    layout = [_shape(rep_, (rows,), i32)] * 3 + [
+        _shape(rep_, (rows, SLOTS), i32), _shape(rep_, (rows, SLOTS), f32),
+        _shape(rep_, (1024,), i32), _shape(rep_, (1024,), i32), _shape(rep_, (1024,), f32),
+        _shape(rep_, (rows,), f32), _shape(rep_, (rows,), f32), _shape(rep_, (), f32),
+    ]
+    compiled = rep._solve_full_fn.lower(*per_op, *layout).compile()
+    text = compiled.as_text()
+    assert "sqrt" in text and "bitcast-convert" in text
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    # No [W, C] heuristic input: the per-op arguments are [1, C] columns.
+    assert mem.argument_size_in_bytes < rows * C
+    assert used < 16e9

@@ -290,3 +290,75 @@ class TestFrontierKernel:
         assert pn.max_deg == 3          # vertex 1 has in-neighbors {0, 2, 0}
         assert pn.mask.sum() == 4
         np.testing.assert_allclose(np.sort(pn.w[1][pn.mask[1] > 0]), [1.0, 3.0, 4.0])
+
+
+class TestHeuristicRows:
+    """The A* heuristic rows are computed inside the solve, on the device,
+    and must equal NumPy's float32 ``sqrt(dx*dx + dy*dy)`` bit for bit."""
+
+    @staticmethod
+    def _values():
+        rng = np.random.default_rng(11)
+        return np.concatenate([
+            rng.uniform(0.0, 200.0, 1_000_000),           # the GIS range of dx² + dy²
+            [0.0],
+            2.0 ** np.arange(-40, 8),                     # powers of two
+            (np.arange(1, 4097) / 64.0) ** 2,             # exact squares
+            np.arange(0, 15, dtype=np.float64) ** 2,
+        ]).astype(np.float32)
+
+    @pytest.mark.parametrize("nudge", [-4, -3, -1, 0, 1, 3, 4])
+    def test_round_sqrt_recovers_numpy(self, nudge):
+        """From NumPy's ``sqrt`` one ulp down, unchanged and one ulp up,
+        three ulps off as a TPU v5e's ``sqrt`` can be, and at the edge of
+        the corrected range, the rounding step returns NumPy's ``sqrt``
+        exactly."""
+        import jax
+
+        from repro.core.traffic_batched import _SQRT_ULPS, _round_sqrt
+
+        assert abs(nudge) <= _SQRT_ULPS
+        x = self._values()
+        want = np.sqrt(x)
+        start = (want.view(np.int32) + nudge).view(np.float32)
+        start = np.where(x > 0, start, np.float32(0)).astype(np.float32)
+        got = np.asarray(jax.jit(_round_sqrt)(x, start))
+        assert np.array_equal(got.view(np.int32), want.view(np.int32))
+
+    def test_device_rows_equal_host_rows_on_gis(self, gis):
+        """The solve's heuristic rows (whole graph to 128 destinations) are
+        NumPy's, and need no rounding step where ``sqrt`` rounds correctly."""
+        import jax.numpy as jnp
+
+        from repro.core.traffic_batched import _device_h
+
+        eng = BatchedTrafficEngine(gis, "gis_short")
+        window = np.arange(gis.n_nodes, dtype=np.int64)
+        ends = np.random.default_rng(0).choice(gis.n_nodes, 128, replace=False)
+        h, moved = _device_h(jnp.asarray(eng._lon), jnp.asarray(eng._lat),
+                             jnp.asarray(eng._lon[ends]), jnp.asarray(eng._lat[ends]))
+        host = eng._host_h(window, ends)
+        assert np.array_equal(np.asarray(h).view(np.int32), host.view(np.int32))
+        assert not np.asarray(moved).any()
+
+    @pytest.mark.parametrize("full", [True, False])
+    def test_stacked_problem_grows_with_rows_plus_ops(self, gis, full):
+        """A chunk's stacked solve inputs hold no ``[W, C]`` array: from 32
+        to 128 ops they grow by a few bytes per op, not by a row per op."""
+        from repro.core.traffic_sharded import get_replayer
+        from repro.launch.mesh import make_replay_mesh
+
+        rep = get_replayer(gis, "gis_short", make_replay_mesh(1))
+        eng = rep.engine
+        rng = np.random.default_rng(1)
+        v = rng.choice(gis.n_nodes, 2, replace=False)
+        cross = np.zeros(gis.n_nodes, np.int32)
+        sizes = {}
+        for c in (32, 128):
+            # Every op runs the same route, so the window is the same.
+            srcs, dsts = np.full(c, v[0]), np.full(c, v[1])
+            args, _, w_real, _, _ = eng.build_sssp_problem(
+                srcs, dsts, np.ones(c, bool), cross, full)
+            sizes[c] = (sum(a.nbytes for a in rep._stack_problems([args])), w_real)
+        assert sizes[32][1] == sizes[128][1] > 100
+        assert 0 < sizes[128][0] - sizes[32][0] <= 96 * 32
