@@ -11,6 +11,7 @@
 * ``Reference``: the four counters of the routes' A* expansion sets, from
   float32 Dijkstra searches on all cores but one; its control runs the
   same searches in bfloat16.
+* ``TINY``: the CPU rehearsal size.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import numpy as np
 from bench.reference import graphs, oplogs, oracle
 
 WORKERS = max(1, (os.cpu_count() or 2) - 1)
+TINY = {"n_nodes": 3000, "n_edges": None}
 
 
 def build(config: dict) -> graphs.EdgeList:

@@ -8,6 +8,7 @@
   are integers, so the control breaks a stated guarantee instead of
   dropping a precision: each step's potentially-global action is booked at
   its sender, not its receiver.
+* ``TINY``: the CPU rehearsal size.
 """
 
 from __future__ import annotations
@@ -15,6 +16,8 @@ from __future__ import annotations
 import numpy as np
 
 from bench.reference import graphs, oplogs, oracle
+
+TINY = {"n_nodes": 5000, "n_edges": None, "log_ops": 1000}
 
 
 def build(config: dict) -> graphs.EdgeList:

@@ -21,9 +21,10 @@ class Dynamic(Driver):
         from repro.core.dynamic_runtime import DynamicExperimentRuntime
 
         svc = self.build_service()
-        starts, ends = next(self.op_source())
-        self.ops_starts = starts
-        self.ops = self.oplog(starts, ends)
+        # The evaluation log as replayed, kept whole for the check (a
+        # target search stops where it finds its target).
+        self.log = next(self.op_source())
+        self.ops = self.oplog(*self.log)
         self.moves_rng = rng_for(self.seed, "dynamism")
         self.runtime = DynamicExperimentRuntime(svc, insert_method="random", seed=0)
         self.runtime.begin(self.ops)
@@ -114,7 +115,7 @@ class Dynamic(Driver):
         ref = self.reference()
         bad = 0
         for i in range(first, first + self.slices):
-            want = ref.counters(self.maps[i + 1], self.ops_starts, None)
+            want = ref.counters(self.maps[i + 1], *self.log)
             bad += oracle.mismatches(self.results[i], want)[0]
         limit = self.config["repair_mismatch_limit"]
         return {
@@ -131,7 +132,7 @@ class Dynamic(Driver):
             self.repaired[i] = maps[i]
         ctl = self.reference(control=True)
         for i in range(self.WARMUP_SLICES, self.WARMUP_SLICES + self.slices):
-            self.results[i] = ctl.counters(self.maps[i + 1], self.ops_starts, None)
+            self.results[i] = ctl.counters(self.maps[i + 1], *self.log)
 
 
 DRIVER = Dynamic

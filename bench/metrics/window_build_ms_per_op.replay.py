@@ -2,10 +2,10 @@
 per op replayed, in ms.
 
 Source: the program's span ``sssp.window_build`` (box selection, capped
-gather layout, heuristic rows and their pull to the host) over the traced
-window, divided by the program's ``replay.ops`` counter of the same window
-(``bench/program.py``). Nothing for a program without that span. Moves
-``ops_per_s``.
+gather layout and the ops' destination coordinates; the heuristic rows are
+computed inside the solve) over the traced window, divided by the
+program's ``replay.ops`` counter of the same window (``bench/program.py``).
+Nothing for a program without that span. Moves ``ops_per_s``.
 """
 
 from bench import program

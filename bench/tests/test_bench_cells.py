@@ -18,13 +18,44 @@ from bench import trace as trace_mod
 
 BENCH = harness.load_benchmark()
 CELLS = [c["name"] for c in BENCH["workloads"]]
-TINY = {"twitter": {"n_nodes": 5000, "n_edges": None, "log_ops": 1000},
-        "gis": {"n_nodes": 3000, "n_edges": None}}
 
 
 def tiny(cell_name: str) -> dict:
-    cell = harness.find_cell(BENCH, cell_name)
-    return TINY[cell["config"].split("_")[0]]
+    """The cell's CPU rehearsal sizes: its dataset module's ``TINY``."""
+    from bench import drivers
+
+    _, config, _, _ = harness.load_cell(cell_name)
+    dataset = drivers.plugin("datasets", config["dataset"])
+    if not hasattr(dataset, "TINY"):
+        raise LookupError(f"{dataset.__name__} states no TINY, the configuration "
+                          f"overrides at which its graph is built for a CPU rehearsal")
+    return dict(dataset.TINY)
+
+
+@pytest.mark.parametrize("config_name", [c["name"] for c in BENCH["configs"]])
+def test_rehearsal_sizes_come_from_the_dataset_module(config_name):
+    from bench import drivers
+
+    cells = [c["name"] for c in BENCH["workloads"] if c["config"] == config_name]
+    assert cells, config_name
+    _, config, _, _ = harness.load_cell(cells[0])
+    want = drivers.plugin("datasets", config["dataset"]).TINY
+    assert set(want) <= set(config), "TINY overrides keys of the configuration"
+    assert all(tiny(c) == want for c in cells)
+
+
+def test_rehearsal_sizes_name_a_dataset_module_without_them(monkeypatch):
+    import re
+    import sys
+    import types
+
+    name = "bench.datasets.sizeless"
+    monkeypatch.setitem(sys.modules, name, types.ModuleType(name))
+    cell, config, mix, bench = harness.load_cell(CELLS[0])
+    monkeypatch.setattr(harness, "load_cell", lambda workload, root=harness.ROOT: (
+        cell, {**config, "dataset": "sizeless"}, mix, bench))
+    with pytest.raises(LookupError, match=re.escape(name)):
+        tiny(CELLS[0])
 
 
 def rehearse(cell_name: str, seconds: float = 0.3, seed: int = 2**33 + 5):
