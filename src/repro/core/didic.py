@@ -171,71 +171,97 @@ def make_spmm(graph: Graph, config: DidicConfig) -> Tuple[Partial, jax.Array]:
 _STEP_CACHE: dict = {}
 
 
-def _make_step(spmm: Partial, degc: jax.Array, config: DidicConfig):
+def _make_step(spmm: Partial, degc: jax.Array, config: DidicConfig,
+               live: Optional[jax.Array] = None, n_live: Optional[int] = None):
     """The single-iteration function for ``spmm``'s graph.
 
     The jitted step is shared per config: ``spmm`` (a
-    :class:`jax.tree_util.Partial` over the graph's tables) and ``degc``
-    are its arguments, so no graph array becomes a compiled constant.
+    :class:`jax.tree_util.Partial` over the graph's tables), ``degc`` and
+    ``live`` are its arguments, so no graph array becomes a compiled
+    constant. ``live`` (bool, one per row) marks the ``n_live`` rows that
+    are vertices: a mesh layout pads each shard's block with rows that are
+    none. Without them every row is a vertex.
     """
+    if live is None:
+        n_live = degc.shape[0]
+        live = jnp.ones(n_live, dtype=bool)
     jitted = _STEP_CACHE.get(config)
     if jitted is None:
         jitted = _STEP_CACHE[config] = _jit_step(config)
-    return functools.partial(jitted, spmm=spmm, degc=degc)
+    return functools.partial(jitted, spmm=spmm, degc=degc, live=live, n_live=n_live)
 
 
 def _jit_step(config: DidicConfig):
-    k = config.k
-
-    @jax.jit
-    def step(w, l, parts, beta, key, smooth_steps, *, spmm, degc):
-        n = w.shape[0]
-        safe_deg = jnp.maximum(degc, 1e-6)
-        onehot = (parts[:, None] == jnp.arange(k, dtype=parts.dtype)[None, :]).astype(w.dtype)
-        # Fresh per-member secondary seed (Eq. 4.5 each iteration; fix #1),
-        # with an ε-floor: a system that loses all members would otherwise
-        # seed zero load forever and stay dead — the ε keeps every system
-        # faintly alive so the ScaleBalance scalars can revive it (matters
-        # on community-free graphs, where partitions otherwise collapse).
-        l = _INIT_LOAD * onehot + 0.01
-        benefit = jnp.where(onehot > 0, _BENEFIT, 1.0).astype(w.dtype)
-
-        def secondary(l, _):
-            lb = l / benefit
-            return l - degc[:, None] * lb + spmm(lb), None
-
-        def primary(carry, _):
-            w, l = carry
-            l, _ = jax.lax.scan(secondary, l, None, length=config.secondary_steps)
-            w_new = w + l - degc[:, None] * w + spmm(w)
-            return (w_new, l), None
-
-        (w, l), _ = jax.lax.scan(primary, (w, l), None, length=config.primary_steps)
-        w = w / jnp.maximum(w.mean(), 1e-6)  # column-common rescale (fix #1)
-
-        # Annealed lazy-random-walk assignment smoothing (fixes #3, #4).
-        def smooth_body(_, x):
-            return 0.5 * x + 0.5 * spmm(x) / safe_deg[:, None]
-
-        smoothed = jax.lax.fori_loop(0, smooth_steps, smooth_body, w)
-
-        # ScaleBalance (fix #2): fit β so argmax sizes approach N/k.
-        tgt = n / k
-
-        def bal(_, beta):
-            p = jnp.argmax(smoothed * beta[None, :], axis=1)
-            sizes = jnp.bincount(p, length=k).astype(w.dtype)
-            return jnp.clip(
-                beta * (tgt / jnp.maximum(sizes, 1.0)) ** config.balance_exp, 1e-3, 1e3
-            )
-
-        beta = jax.lax.fori_loop(0, config.balance_iters, bal, beta)
-        new_parts = jnp.argmax(smoothed * beta[None, :], axis=1).astype(jnp.int32)
-        commit = jax.random.bernoulli(key, config.commit_prob, (n,))
-        parts = jnp.where(commit, new_parts, parts)
-        return w, l, parts, beta
+    @functools.partial(jax.jit, static_argnames="n_live")
+    def step(w, l, parts, beta, key, smooth_steps, *, spmm, degc, live, n_live):
+        return _step_body(config, spmm, degc, live, n_live, w, l, parts, beta, key,
+                          smooth_steps)
 
     return step
+
+
+def _step_body(config: DidicConfig, spmm: Callable, degc, live, n_live, w, l, parts, beta,
+               key, smooth_steps):
+    """One DiDiC iteration, traced inside each path's jitted step.
+
+    Rows outside ``live`` (a mesh layout's block padding, a store's spare
+    capacity) are no vertices: they carry no load, keep their part, and
+    count neither in the rescale nor in ScaleBalance's sizes and target,
+    which they would bias towards system 0 far beyond float32 rounding.
+    ``n_live`` counts the live rows: an int where the graph's shape fixes
+    it, a traced float where one program serves a growing graph.
+    """
+    k = config.k
+    n_rows = w.shape[0]
+    livef = live.astype(w.dtype)
+    safe_deg = jnp.maximum(degc, 1e-6)
+    onehot = (
+        parts[:, None] == jnp.arange(k, dtype=parts.dtype)[None, :]
+    ).astype(w.dtype) * livef[:, None]
+    # Fresh per-member secondary seed (Eq. 4.5 each iteration; fix #1),
+    # with an ε-floor: a system that loses all members would otherwise
+    # seed zero load forever and stay dead — the ε keeps every system
+    # faintly alive so the ScaleBalance scalars can revive it (matters
+    # on community-free graphs, where partitions otherwise collapse).
+    l = (_INIT_LOAD * onehot + 0.01) * livef[:, None]
+    benefit = jnp.where(onehot > 0, _BENEFIT, 1.0).astype(w.dtype)
+
+    def secondary(l, _):
+        lb = l / benefit
+        return l - degc[:, None] * lb + spmm(lb), None
+
+    def primary(carry, _):
+        w, l = carry
+        l, _ = jax.lax.scan(secondary, l, None, length=config.secondary_steps)
+        w_new = w + l - degc[:, None] * w + spmm(w)
+        return (w_new, l), None
+
+    (w, l), _ = jax.lax.scan(primary, (w, l), None, length=config.primary_steps)
+    # Column-common rescale over the live rows' mean (fix #1).
+    mean = jnp.where(live[:, None], w, 0.0).sum() / (n_live * k)
+    w = w / jnp.maximum(mean, 1e-6)
+
+    # Annealed lazy-random-walk assignment smoothing (fixes #3, #4).
+    def smooth_body(_, x):
+        return 0.5 * x + 0.5 * spmm(x) / safe_deg[:, None]
+
+    smoothed = jax.lax.fori_loop(0, smooth_steps, smooth_body, w)
+
+    # ScaleBalance (fix #2): fit β so argmax sizes approach N/k.
+    tgt = n_live / k
+
+    def bal(_, beta):
+        p = jnp.argmax(smoothed * beta[None, :], axis=1)
+        sizes = jnp.bincount(jnp.where(live, p, k), length=k + 1)[:k].astype(w.dtype)
+        return jnp.clip(
+            beta * (tgt / jnp.maximum(sizes, 1.0)) ** config.balance_exp, 1e-3, 1e3
+        )
+
+    beta = jax.lax.fori_loop(0, config.balance_iters, bal, beta)
+    new_parts = jnp.argmax(smoothed * beta[None, :], axis=1).astype(jnp.int32)
+    commit = jax.random.bernoulli(key, config.commit_prob, (n_rows,))
+    parts = jnp.where(commit & live, new_parts, parts)
+    return w, l, parts, beta
 
 
 # ===========================================================================
@@ -283,9 +309,10 @@ def _make_overlay_step(config: DidicConfig):
 
     Module-level cache keyed by config (the legacy step hangs off the
     graph-owned spmm closure instead, which is exactly what forces a
-    retrace per grown graph). Reductions are masked to the live extent so
-    the live prefix sees the same *algorithm* as the legacy step — the
-    padded float sums reassociate, so values are close but not
+    retrace per grown graph). It traces :func:`_step_body` with the rows
+    past the live extent masked, so the live prefix sees the same
+    *algorithm* as the legacy step — the padded float sums reassociate,
+    so values are close but not
     bit-identical to the legacy path; both the host and device services
     route store-backed maintenance through here, which keeps their
     host-vs-device parity contract exact.
@@ -293,64 +320,18 @@ def _make_overlay_step(config: DidicConfig):
     step = _OVERLAY_STEP_CACHE.get(config)
     if step is not None:
         return step
-    k = config.k
 
     @jax.jit
     def step(w, l, parts, beta, key, smooth_steps, s, r, ce, degc, live_n):
         n_rows = w.shape[0]
-        live = jnp.arange(n_rows, dtype=jnp.int32) < live_n
-        livef = live.astype(w.dtype)
 
         def spmm(x):
             contrib = ce[:, None] * jnp.take(x, r, axis=0)
             return jax.ops.segment_sum(contrib, s, num_segments=n_rows)
 
-        onehot = (
-            parts[:, None] == jnp.arange(k, dtype=parts.dtype)[None, :]
-        ).astype(w.dtype) * livef[:, None]
-        # Fresh per-member seed with the ε-floor (legacy fix #1), masked so
-        # dead rows carry exactly zero load through every diffusion fold.
-        l = (_INIT_LOAD * onehot + 0.01) * livef[:, None]
-        benefit = jnp.where(onehot > 0, _BENEFIT, 1.0).astype(w.dtype)
-
-        def secondary(l, _):
-            lb = l / benefit
-            return l - degc[:, None] * lb + spmm(lb), None
-
-        def primary(carry, _):
-            w, l = carry
-            l, _ = jax.lax.scan(secondary, l, None, length=config.secondary_steps)
-            w_new = w + l - degc[:, None] * w + spmm(w)
-            return (w_new, l), None
-
-        (w, l), _ = jax.lax.scan(primary, (w, l), None, length=config.primary_steps)
-        livef_n = live_n.astype(w.dtype)
-        # Column-common rescale over the *live* mean (dead rows sum 0).
-        w = w / jnp.maximum(w.sum() / (livef_n * k), 1e-6)
-
-        safe_deg = jnp.maximum(degc, 1e-6)
-
-        def smooth_body(_, x):
-            return 0.5 * x + 0.5 * spmm(x) / safe_deg[:, None]
-
-        smoothed = jax.lax.fori_loop(0, smooth_steps, smooth_body, w)
-
-        tgt = livef_n / k
-
-        def bal(_, beta):
-            p = jnp.argmax(smoothed * beta[None, :], axis=1)
-            sizes = jnp.bincount(
-                jnp.where(live, p, k), length=k + 1
-            )[:k].astype(w.dtype)
-            return jnp.clip(
-                beta * (tgt / jnp.maximum(sizes, 1.0)) ** config.balance_exp, 1e-3, 1e3
-            )
-
-        beta = jax.lax.fori_loop(0, config.balance_iters, bal, beta)
-        new_parts = jnp.argmax(smoothed * beta[None, :], axis=1).astype(jnp.int32)
-        commit = jax.random.bernoulli(key, config.commit_prob, (n_rows,))
-        parts = jnp.where(commit & live, new_parts, parts)
-        return w, l, parts, beta
+        live = jnp.arange(n_rows, dtype=jnp.int32) < live_n
+        return _step_body(config, spmm, degc, live, live_n.astype(w.dtype), w, l, parts, beta,
+                          key, smooth_steps)
 
     _OVERLAY_STEP_CACHE[config] = step
     return step

@@ -50,13 +50,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.didic import (
-    _BENEFIT,
     _INIT_LOAD,
     DidicConfig,
     DidicState,
     _init_state,
     _make_step,
     _smooth_schedule,
+    _step_body,
 )
 from repro.core import partitioners
 from repro.graphs.structure import Graph
@@ -128,6 +128,14 @@ def _mesh_program_build(graph, mesh, data_axes, n_shards, bootstrap_parts,
         NamedSharding(auto_axes(mesh), P(data_axes)),
     )
     return (layout, spmm_halo, degc)
+
+
+def _live_rows(layout, mesh, data_axes) -> jax.Array:
+    """bool [padded_n]: the layout's rows that hold a vertex, mesh-sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(jnp.asarray(layout.new_to_old >= 0),
+                          NamedSharding(mesh, P(data_axes)))
 
 
 def _sharded_state(layout, k: int, parts_padded: np.ndarray, mesh, data_axes):
@@ -290,7 +298,6 @@ def _make_mesh_overlay_step(mesh, data_axes: Tuple[str, ...],
 
     from jax.sharding import PartitionSpec as P
 
-    k = config.k
     spec_x = P(data_axes, None)
     spec_tab = P(data_axes, None)
 
@@ -315,56 +322,11 @@ def _make_mesh_overlay_step(mesh, data_axes: Tuple[str, ...],
     @jax.jit
     def step(w, l, parts, beta, key, smooth_steps,
              esrc, edst, ew, emask, bidx, gsrc, degc, live, live_n):
-        n_rows = w.shape[0]
-        livef = live.astype(w.dtype)
-
         def spmm(x):
             return smapped(x, esrc, edst, ew, emask, bidx, gsrc)
 
-        onehot = (
-            parts[:, None] == jnp.arange(k, dtype=parts.dtype)[None, :]
-        ).astype(w.dtype) * livef[:, None]
-        l = (_INIT_LOAD * onehot + 0.01) * livef[:, None]
-        benefit = jnp.where(onehot > 0, _BENEFIT, 1.0).astype(w.dtype)
-
-        def secondary(l, _):
-            lb = l / benefit
-            return l - degc[:, None] * lb + spmm(lb), None
-
-        def primary(carry, _):
-            w, l = carry
-            l, _ = jax.lax.scan(secondary, l, None, length=config.secondary_steps)
-            w_new = w + l - degc[:, None] * w + spmm(w)
-            return (w_new, l), None
-
-        (w, l), _ = jax.lax.scan(primary, (w, l), None, length=config.primary_steps)
-        livef_n = live_n.astype(w.dtype)
-        w = w / jnp.maximum(w.sum() / (livef_n * k), 1e-6)
-
-        safe_deg = jnp.maximum(degc, 1e-6)
-
-        def smooth_body(_, x):
-            return 0.5 * x + 0.5 * spmm(x) / safe_deg[:, None]
-
-        smoothed = jax.lax.fori_loop(0, smooth_steps, smooth_body, w)
-
-        tgt = livef_n / k
-
-        def bal(_, beta):
-            p = jnp.argmax(smoothed * beta[None, :], axis=1)
-            sizes = jnp.bincount(
-                jnp.where(live, p, k), length=k + 1
-            )[:k].astype(w.dtype)
-            return jnp.clip(
-                beta * (tgt / jnp.maximum(sizes, 1.0)) ** config.balance_exp,
-                1e-3, 1e3,
-            )
-
-        beta = jax.lax.fori_loop(0, config.balance_iters, bal, beta)
-        new_parts = jnp.argmax(smoothed * beta[None, :], axis=1).astype(jnp.int32)
-        commit = jax.random.bernoulli(key, config.commit_prob, (n_rows,))
-        parts = jnp.where(commit & live, new_parts, parts)
-        return w, l, parts, beta
+        return _step_body(config, spmm, degc, live, live_n.astype(w.dtype), w, l, parts, beta,
+                          key, smooth_steps)
 
     _MESH_OVERLAY_STEP_CACHE[cache_key] = step
     return step
@@ -453,7 +415,8 @@ def didic_partition_distributed(
     state = _sharded_state(layout, config.k, parts0, mesh, data_axes)
     w, l, parts, beta = state.w, state.l, state.parts, state.beta
 
-    step = _make_step(spmm_halo, degc, config)
+    step = _make_step(spmm_halo, degc, config, _live_rows(layout, mesh, data_axes),
+                      graph.n_nodes)
     schedule = _smooth_schedule(config, config.iterations, start_wide=False)
     key = jax.random.PRNGKey(seed)
     for it in range(config.iterations):
@@ -522,7 +485,8 @@ def didic_refine_distributed(
     w, l, beta = state.w, state.l, state.beta
     parts_cur = parts_j
 
-    step = _make_step(spmm_halo, degc, config)
+    step = _make_step(spmm_halo, degc, config, _live_rows(layout, mesh, data_axes),
+                      graph.n_nodes)
     schedule = _smooth_schedule(config, iterations, start_wide=True)
     key = jax.random.PRNGKey(seed)
     for it in range(iterations):

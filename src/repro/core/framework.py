@@ -635,11 +635,13 @@ class PartitionedGraphService:
 
     def _repair(self, parts: np.ndarray, iterations: int) -> np.ndarray:
         """DiDiC maintenance from ``parts``, until the new map is on the
-        host; counts the iterations and their sparse products."""
+        host; counts the iterations, their sparse products and their
+        smoothing steps (a repair smooths at the full width, ``smooth_cap``)."""
         c = self.runtime.config
         tracing.count("didic.iterations", iterations)
         tracing.count("didic.spmms", iterations * (
             c.primary_steps * (c.secondary_steps + 1) + c.smooth_cap))
+        tracing.count("didic.smooth_steps", iterations * c.smooth_cap)
         with tracing.span("didic.repair"):
             return self._maintain_attempt(
                 lambda: self.runtime.maintain(self.graph, parts,

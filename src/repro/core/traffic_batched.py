@@ -548,9 +548,20 @@ class BatchedTrafficEngine:
         per_op = p[starts, levels]       # [n_ops, 2]
         return per_op[:, 0], per_op[:, 1]
 
-    def _compile_bfs_log(self, ops) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-op expansion levels + per-level start histograms (cached
-        per engine structure version — growth invalidates the entry)."""
+    def bfs_log(self, ops) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`_compile_bfs_log` under the span ``bfs.compile_log``;
+        books the log's expansion levels (``bfs.levels``) and the ops whose
+        end lies in their start's subtree (``bfs.targets_found``)."""
+        with tracing.span("bfs.compile_log"):
+            levels, c_stack, found = self._compile_bfs_log(ops)
+        tracing.count("bfs.levels", int(levels.sum(dtype=np.int64)))
+        tracing.count("bfs.targets_found", found)
+        return levels, c_stack
+
+    def _compile_bfs_log(self, ops) -> Tuple[np.ndarray, np.ndarray, int]:
+        """Per-op expansion levels, per-level start histograms and the
+        number of ops that find their end (cached per engine structure
+        version — growth invalidates the entry)."""
         cache = ops.__dict__.setdefault("_bfs_compile_cache", {})
         ckey = (self, self._struct_version)
         if ckey in cache:
@@ -560,6 +571,7 @@ class BatchedTrafficEngine:
         starts = ops.starts.astype(np.int64)
         if self.pattern == "twitter":
             levels = np.full(n_ops, 2, dtype=np.int64)
+            found = 0
         else:
             depth = self.graph.node_attrs["depth"].astype(np.int64)
             parent = self.graph.node_attrs["parent"].astype(np.int64)
@@ -572,16 +584,17 @@ class BatchedTrafficEngine:
                 steps = np.maximum(steps - 1, 0)
             is_descendant = (l_raw > 0) & (cur == starts)
             levels = np.where(is_descendant, np.minimum(l_raw, t), t)
+            found = int(np.count_nonzero(is_descendant))
         # c_stack[l, u] = #ops with start u still expanding at level l (L > l).
         hist = np.zeros((t + 1, self.n_nodes), dtype=np.int32)
         np.add.at(hist, (np.minimum(levels, t) - 1, starts), 1)
         c_stack = hist[::-1].cumsum(axis=0)[::-1].copy()[:t]
-        out = (levels.astype(np.int32), c_stack)
+        out = (levels.astype(np.int32), c_stack, found)
         cache[ckey] = out
         return out
 
     def _run_bfs(self, ops, cross_deg: np.ndarray):
-        levels, c_stack = self._compile_bfs_log(ops)
+        levels, c_stack = self.bfs_log(ops)
         edges, cross = self._run_fn(
             jnp.asarray(ops.starts.astype(np.int32)),
             jnp.asarray(levels),

@@ -345,6 +345,7 @@ class ShardedTrafficReplayer:
                  state: Optional[ResidentReplayState] = None):
         eng = self.engine
         n_ops = ops.n_ops
+        levels, _ = eng.bfs_log(ops)
         if state is not None and state.pending_dirty is not None:
             # BFS artifacts (ancestor levels, subtree prefix tables,
             # frontier mass) are global properties of the tree/edge list —
@@ -362,7 +363,6 @@ class ShardedTrafficReplayer:
             )).reshape(-1)[:n_ops].astype(np.int64)
             return state.per_op_edges, cross, state.tm
 
-        levels, _ = eng._compile_bfs_log(ops)
         starts = ops.starts.astype(np.int32)
         st_dev = jnp.asarray(self._shard_pad(starts, 0))
         lvl_dev = jnp.asarray(self._shard_pad(levels, 0))

@@ -116,6 +116,29 @@ class TestDidic:
         assert f"{edges}x" in hlo
         assert re.search(rf"constant.*tensor<(\d+x)?{edges}x", hlo) is None
 
+    def test_mesh_refine_equals_single_device_refine(self, fs):
+        """A mesh layout pads each shard's block to a multiple of 8 rows
+        (3,705 vertices: 3 padding rows). Padding rows are no vertices:
+        counted in ScaleBalance's sizes and target and in the rescale,
+        they biased β, and the one-device mesh repair differed from the
+        single-device one in most vertices."""
+        from repro.core.didic_distributed import didic_refine_distributed
+        from repro.launch.mesh import make_replay_mesh
+
+        assert fs.n_nodes % 8
+        cfg = DidicConfig(k=4, smooth_cap=256)
+        rng = np.random.default_rng(0)
+        parts = rng.integers(0, 4, fs.n_nodes).astype(np.int32)
+        mesh_state = shared_state = None
+        for _ in range(2):
+            moved = rng.integers(0, fs.n_nodes, fs.n_nodes // 20)
+            parts[moved] = rng.integers(0, 4, moved.shape[0])
+            shared, shared_state = didic_refine(fs, parts, cfg, state=shared_state)
+            mesh, mesh_state = didic_refine_distributed(fs, parts, cfg, make_replay_mesh(1),
+                                                        state=mesh_state)
+            np.testing.assert_array_equal(mesh, shared)
+            parts = shared.copy()
+
 
 class TestPartitioners:
     def test_hardcoded_filesystem_subtrees(self, fs):

@@ -250,3 +250,92 @@ def test_gis_engines_match_oracle_with_equal_heuristic_corrections(case):
     for name, got in results.items():
         for f in COUNTERS:
             np.testing.assert_array_equal(getattr(got, f), getattr(ref, f), err_msg=name)
+
+
+# -- target searches and DiDiC repairs ---------------------------------------
+
+def _fs_log():
+    """A filesystem graph and a log of target searches, half of whose ends
+    are random files and folders, mostly outside the start's subtree."""
+    from repro.core.traffic import OpLog
+    from repro.graphs.generators import FS_FILE, FS_FOLDER, filesystem_tree
+
+    g = filesystem_tree(0.01, 0)
+    log = generate_ops(g, n_ops=120, seed=6, pattern="filesystem")
+    nt = g.node_attrs["node_type"]
+    pool = np.nonzero((nt == FS_FILE) | (nt == FS_FOLDER))[0]
+    ends = log.ends.copy()
+    ends[::2] = np.random.default_rng(7).choice(pool, ends[::2].shape[0])
+    return g, OpLog("filesystem", log.starts, ends, log.t_l, log.t_pg)
+
+
+def _levels_of(g, ops, max_levels):
+    """(Σ levels, ops that find their end), one op at a time up the parent
+    pointers: an end in the start's subtree is found at its depth below the
+    start; any other op expands all ``max_levels`` levels."""
+    depth, parent = g.node_attrs["depth"], g.node_attrs["parent"]
+    levels = found = 0
+    for s, e in zip(ops.starts.tolist(), ops.ends.tolist()):
+        v = e
+        while parent[v] >= 0 and depth[v] > depth[s]:
+            v = parent[v]
+        if v == s and depth[e] > depth[s]:
+            levels += min(int(depth[e] - depth[s]), max_levels)
+            found += 1
+        else:
+            levels += max_levels
+    return levels, found
+
+
+@pytest.mark.parametrize("replay", ["batched", "cold", "resident"])
+def test_bfs_replay_books_levels_and_targets(replay):
+    from repro.core.traffic_batched import execute_ops_batched
+    from repro.core.traffic_sharded import get_replayer
+
+    g, ops = _fs_log()
+    parts = partitioners.random_partition(g.n_nodes, 4, seed=0)
+    rep = get_replayer(g, "filesystem", make_replay_mesh(1))
+    if replay == "batched":
+        run = lambda: execute_ops_batched(g, ops, parts, 4)  # noqa: E731
+    else:
+        run = lambda: rep.replay(ops, parts, 4, resident=replay == "resident")  # noqa: E731
+    run()  # compiles; a resident replay solves and keeps its state here
+    tracing.reset()
+    run()
+    snap = tracing.snapshot()
+    levels, found = _levels_of(g, ops, rep.engine.max_levels)
+    assert 0 < found < ops.n_ops
+    assert snap["counters"]["bfs.levels"] == levels
+    assert snap["counters"]["bfs.targets_found"] == found
+    span = snap["spans"]["bfs.compile_log"]
+    assert (span["calls"], span["parent"]) == (1, "replay")
+
+
+def test_twitter_replay_books_two_levels_an_op_and_no_targets():
+    from repro.core.traffic_sharded import get_replayer
+
+    g = datasets.load("twitter", scale=0.003)
+    ops = generate_ops(g, n_ops=90, seed=2, pattern="twitter")
+    parts = partitioners.random_partition(g.n_nodes, 4, seed=0)
+    rep = get_replayer(g, "twitter", make_replay_mesh(1))
+    for resident in (False, True, True):
+        tracing.reset()
+        rep.replay(ops, parts, 4, resident=resident)
+        c = tracing.snapshot()["counters"]
+        assert (c["bfs.levels"], c["bfs.targets_found"]) == (2 * ops.n_ops, 0)
+
+
+@pytest.mark.parametrize("smooth_cap", [64, 256])
+def test_repair_books_its_smoothing_width(smooth_cap):
+    from repro.core.didic import DidicConfig
+    from repro.core.framework import PartitionedGraphService
+    from repro.graphs.generators import filesystem_tree
+
+    g = filesystem_tree(0.005, 0)
+    cfg = DidicConfig(k=4, iterations=2, smooth_cap=smooth_cap)
+    svc = PartitionedGraphService(g, 4, didic=cfg).partition_didic(seed=0)
+    tracing.reset()
+    svc.maintain(1)
+    c = tracing.snapshot()["counters"]
+    assert c["didic.smooth_steps"] == smooth_cap
+    assert c["didic.spmms"] == cfg.primary_steps * (cfg.secondary_steps + 1) + smooth_cap
